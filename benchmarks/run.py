@@ -94,6 +94,7 @@ from repro.core.weights import (  # noqa: E402
     min_weight,
     mv_weight,
 )
+from repro.launch.cache import init_compile_cache  # noqa: E402
 
 ROWS: list[tuple[str, float, str]] = []
 
@@ -1578,6 +1579,7 @@ def scale_bench(scale: float, calls: int = 96, cycles: int = 4,
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=float, default=0.25,
                     help="matrix-size scale vs the paper's AWS experiment")

@@ -83,6 +83,7 @@ from .transport import (  # noqa: F401
     Transport,
     make_transport,
     resolve_transport,
+    worker_env,
 )
 from .wire import (  # noqa: F401
     Heartbeat,

@@ -93,6 +93,8 @@ ENV_MAX_INFLIGHT = "REPRO_FLEET_MAX_INFLIGHT"
 ENV_MIN_WORKERS = "REPRO_FLEET_MIN_WORKERS"
 _POLL_S = 0.02          # transport poll slice on the pump thread
 _TICK_S = 0.025         # watchdog period (suspicion + deadlines)
+# results a worker's throughput EWMA needs before it sets a capacity
+MIN_RATE_SAMPLES = 3
 
 
 def default_max_inflight() -> int:
@@ -544,6 +546,7 @@ class CodedFleet:
         self._draining: dict[int, tuple] = {}       # worker -> (deadline, fut)
         self._join_waiters: dict[int, concurrent.futures.Future] = {}
         self._rate: dict[int, float] = {}           # worker -> work/s EWMA
+        self._rate_n: dict[int, int] = {}           # worker -> EWMA samples
         self._all_dead: RuntimeError | None = None
         self._orphan = {"deaths": 0, "suspected": 0}    # between-rounds
         self._next_plan_id = 1
@@ -722,7 +725,10 @@ class CodedFleet:
         """Integer device speeds from the throughput EWMAs (submit ->
         result work/s), quantized to ``1..levels`` -- the ``capacities``
         vector ``proposed-hetero`` virtualizes devices with.  Workers
-        without a measured rate yet get the median live rate.
+        without a measured rate yet get the median live rate; an EWMA
+        counts as measured after ``MIN_RATE_SAMPLES`` results (one
+        round's arrival order alone spreads identical workers' rates
+        several-fold).
 
         ``rates`` (worker -> work/s) substitutes an external
         measurement for the heartbeat-path EWMAs -- e.g. the per-worker
@@ -731,7 +737,11 @@ class CodedFleet:
         the whole submit->result loop (a higher-fidelity capacity
         signal under queueing or wire noise)."""
         ws = list(workers) if workers is not None else self._live()
-        src = self._rate if rates is None else rates
+        if rates is None:
+            src = {w: r for w, r in self._rate.items()
+                   if self._rate_n.get(w, 0) >= MIN_RATE_SAMPLES}
+        else:
+            src = rates
         rates = [src.get(w, 0.0) for w in ws]
         known = sorted(r for r in rates if r > 0)
         if not known:
@@ -1284,6 +1294,7 @@ class CodedFleet:
             prev = self._rate.get(ev.worker)
             self._rate[ev.worker] = rate if prev is None \
                 else 0.7 * prev + 0.3 * rate
+            self._rate_n[ev.worker] = self._rate_n.get(ev.worker, 0) + 1
         dec = self._decodable(rnd)
         if dec is not None:
             self._finish_round(rnd, *dec)

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import os
 
-from .base import Transport  # noqa: F401
+from .base import Transport, worker_env  # noqa: F401
 from .memory import MemoryTransport
 from .pipe import PipeTransport
 from .shm import ShmTransport

@@ -20,10 +20,44 @@ the death event that follows re-accounts the round via requeue;
 
 from __future__ import annotations
 
+import os
 import queue
+import threading
 
 from ..faults import NoFaults
 from ..wire import Heartbeat, Task
+
+# Workers compute with numpy/scipy.  A worker process that reached for
+# the accelerator would contend for the chip its coordinator holds (a
+# chip belongs to one process), so every worker starts CPU-only.
+WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
+
+_ENV_LOCK = threading.Lock()
+
+
+def worker_env(base=None) -> dict:
+    """Environment for a worker process: ``base`` (default: this
+    process's environment) with JAX held to the CPU.  Launchers of
+    ``python -m repro.cluster.worker`` processes pass it as ``env``."""
+    return {**(os.environ if base is None else base), **WORKER_ENV}
+
+
+def start_worker_process(proc) -> None:
+    """``proc.start()`` for a ``multiprocessing`` worker, with the
+    child's environment held to the CPU.  Spawned children inherit this
+    process's environment at start and take no ``env`` argument, so the
+    variables are set around the start (under a lock) and restored."""
+    with _ENV_LOCK:
+        saved = {k: os.environ.get(k) for k in WORKER_ENV}
+        os.environ.update(WORKER_ENV)
+        try:
+            proc.start()
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
 
 
 class Transport:

@@ -27,7 +27,7 @@ import time
 from ..faults import from_spec
 from ..wire import Task, TaskResult, WorkerJoin, death_notice, decode_event
 from ..worker import serve_loop, start_heartbeat
-from .base import Transport
+from .base import Transport, start_worker_process
 
 
 def _pipe_worker_main(conn, worker_id: int, fault_spec, heartbeat_s: float
@@ -96,7 +96,7 @@ class PipeTransport(Transport):
             target=_pipe_worker_main,
             args=(child, w, self.faults.to_spec(), self.heartbeat_s),
             daemon=True)
-        proc.start()
+        start_worker_process(proc)
         child.close()
         self._conns[w] = conn
         self._procs[w] = proc

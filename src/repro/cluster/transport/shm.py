@@ -52,6 +52,7 @@ import numpy as np
 from ..faults import from_spec
 from ..wire import Task, TaskResult, death_notice, decode_record
 from ..worker import serve_loop, start_heartbeat
+from .base import start_worker_process
 from .pipe import PipeTransport
 
 _REF_META = "shm"          # task meta key: payload refs
@@ -312,7 +313,7 @@ class ShmTransport(PipeTransport):
             target=_shm_worker_main,
             args=(child, w, self.faults.to_spec(), self.heartbeat_s),
             daemon=True)
-        proc.start()
+        start_worker_process(proc)
         child.close()
         self._conns[w] = conn
         self._procs[w] = proc

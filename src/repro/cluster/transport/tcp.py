@@ -63,7 +63,7 @@ from ..wire import (
     welcome_record,
 )
 from ..worker import serve_loop, start_heartbeat
-from .base import Transport
+from .base import Transport, start_worker_process
 
 _LEN = struct.Struct("<I")
 _MAX_FRAME = 1 << 31
@@ -331,7 +331,7 @@ class TcpTransport(Transport):
             args=(self.host, self.port, w, self.faults.to_spec(),
                   self.heartbeat_s, join),
             daemon=True)
-        proc.start()
+        start_worker_process(proc)
         self._procs[w] = proc
 
     def start(self, shard_blobs: list[bytes] | None = None) -> int:

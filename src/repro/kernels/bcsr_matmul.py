@@ -43,6 +43,7 @@ def _bcsr_matmul_kernel(idx_ref, a_ref, b_ref, c_ref):
     b = b_ref[...]             # (bk, bn) tile of B at K-block idx[m, j]
     c_ref[...] += jax.lax.dot_general(
         a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,    # f32 operands stay f32
         preferred_element_type=jnp.float32,
     )
 

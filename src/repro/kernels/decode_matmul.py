@@ -21,6 +21,7 @@ from jax.experimental import pallas as pl
 
 def _decode_kernel(h_ref, y_ref, u_ref):
     u_ref[...] = jnp.dot(h_ref[...], y_ref[...],
+                         precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
 
 

@@ -15,12 +15,18 @@ the multi-pod proof.  Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n_axes: int) -> tuple:
+    # the sharding rules constrain activations and let GSPMD propagate
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_test_mesh(devices: int | None = None):
@@ -31,7 +37,8 @@ def make_test_mesh(devices: int | None = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 # Hardware constants for the roofline (TPU v5e)

@@ -24,9 +24,11 @@ from ..configs.base import CodedConfig
 from ..models import build_model
 from ..runtime import BACKENDS
 from ..serve import Request, ServeEngine
+from .cache import init_compile_cache
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true")

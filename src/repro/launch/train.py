@@ -26,9 +26,11 @@ from ..models import build_model
 from ..optim import AdamWConfig, CompressionConfig
 from ..runtime import BACKENDS, ENV_BACKEND, resolve_backend
 from ..train import TrainConfig, Trainer
+from .cache import init_compile_cache
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -220,10 +220,10 @@ def moe_block_ep(p: dict, x: jnp.ndarray, moe: MoEConfig, mesh,
         out = jax.lax.psum(out.astype(jnp.float32), model_axis)
         return out.astype(xx.dtype).reshape(bl, sl, d), aux
 
-    from ..parallel.ctx import shard_map_compat  # noqa: PLC0415
+    from ..parallel.ctx import auto_mesh  # noqa: PLC0415
 
-    fn = shard_map_compat(
-        inner, mesh=mesh,
+    fn = jax.shard_map(
+        inner, mesh=auto_mesh(mesh),
         in_specs=(P(None, None), P(model_axis, dp_axes[-1], None),
                   P(model_axis, dp_axes[-1], None),
                   P(model_axis, None, dp_axes[-1]),

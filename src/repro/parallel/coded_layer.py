@@ -162,14 +162,15 @@ class CodedLinear:
 
         def worker(coded_shard, xx, dd):
             # coded_shard: (1, d_in, c) local slice
-            y_local = jnp.einsum("tc,...t->...c", coded_shard[0], xx)
+            y_local = jnp.einsum("tc,...t->...c", coded_shard[0], xx,
+                                 precision=jax.lax.Precision.HIGHEST)
             y_all = jax.lax.all_gather(y_local, axis)      # (n, ..., c)
             return self.decode(y_all, dd)
 
-        from .ctx import shard_map_compat  # noqa: PLC0415
+        from .ctx import auto_mesh  # noqa: PLC0415
 
-        fn = shard_map_compat(
-            worker, mesh=mesh,
+        fn = jax.shard_map(
+            worker, mesh=auto_mesh(mesh),
             in_specs=(P(axis), P(), P()),
             out_specs=P(),
             # the decode of the all-gathered results is identical on

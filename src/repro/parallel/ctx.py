@@ -17,23 +17,20 @@ from typing import Callable
 _state = threading.local()
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
+def auto_mesh(mesh):
+    """``mesh`` with every axis of type Auto.
 
-    Newer jax exposes it top-level with ``check_vma``; older releases
-    only have ``jax.experimental.shard_map.shard_map`` with the same
-    knob named ``check_rep``.  Every shard_map call site in the repo
-    (coded layer, expert-parallel MoE) routes through here.
+    The repo's shard_map bodies and sharding constraints are written for
+    GSPMD-propagated (Auto) axes; ``jax.make_mesh`` makes Explicit axes
+    by default, under which a gradient through those bodies asks for a
+    ``jax.set_mesh`` context the callers do not hold.
     """
-    import jax  # noqa: PLC0415
+    from jax.sharding import AxisType, Mesh  # noqa: PLC0415
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm  # noqa: PLC0415
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
 
 
 def _identity(name: str, x):

@@ -31,7 +31,10 @@ to the reference path, so a single call site serves both worlds.
 
 from __future__ import annotations
 
+import functools
 import os
+import threading
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -86,12 +89,17 @@ def is_concrete(*vals) -> bool:
 _is_concrete = is_concrete
 
 
-def _pick_block(size: int, pref: int) -> int:
-    """Largest power-of-two-ish block <= pref dividing ``size``."""
-    b = min(pref, size)
-    while size % b:
-        b //= 2
-    return max(b, 1)
+def lane_tile(size: int, pref: int) -> tuple[int, int]:
+    """(block, padded size) for one kernel dimension.
+
+    A dimension that fits in one block stays whole; a longer one is
+    padded up to a multiple of ``pref`` (itself a multiple of 128), so
+    every block satisfies the TPU tiling rule: its last two dimensions
+    are multiples of (8, 128) or the full array dimensions.
+    """
+    if size <= pref:
+        return size, size
+    return pref, _round_up(size, pref)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
@@ -100,6 +108,48 @@ def _pad_to(x: jnp.ndarray, axis: int, size: int) -> jnp.ndarray:
     pads = [(0, 0)] * x.ndim
     pads[axis] = (0, size - x.shape[axis])
     return jnp.pad(x, pads)
+
+
+# ---------------------------------------------------------------------------
+# Kernel calls: pad to the tiling, run, slice the padding off
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def encode_kernel(blocks, sup, coef, *, interpret: bool = False):
+    """``cyclic_encode`` of (k, T, C) block-columns.
+
+    C stays whole; the row block is the largest power of two from 8 to
+    128 that divides T rounded up to 8, so an aligned T is never copied.
+    """
+    t = blocks.shape[1]
+    t_pad = _round_up(t, 8)
+    bt = 128
+    while t_pad % bt:
+        bt //= 2
+    out = cyclic_encode(_pad_to(blocks, 1, t_pad), sup, coef, bt=bt,
+                        interpret=interpret)
+    return out[:, :t]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def worker_kernel(a_data, a_idx, b, *, interpret: bool = False):
+    """``bcsr_matmul`` C = A^T b for b (t_pad, N), N padded to its tile."""
+    n = b.shape[1]
+    bn, n_pad = lane_tile(n, 128)
+    out = bcsr_matmul(a_data, a_idx, _pad_to(b, 1, n_pad), bn=bn,
+                      interpret=interpret)
+    return out[:, :n]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_kernel(hinv, y, *, interpret: bool = False):
+    """``decode_matmul`` U = hinv @ y for y (k, P), P padded to its tile."""
+    p = y.shape[1]
+    bp, p_pad = lane_tile(p, 512)
+    out = decode_matmul(hinv, _pad_to(y, 1, p_pad), bp=bp,
+                        interpret=interpret)
+    return out[:, :p]
 
 
 # ---------------------------------------------------------------------------
@@ -136,12 +186,7 @@ def encode_blocks(blocks, sup, coef, backend: str | None = None) -> jnp.ndarray:
     sup = jnp.asarray(sup, jnp.int32)
     coef = jnp.asarray(coef, jnp.float32)
     if backend in _KERNEL_BACKENDS:
-        t = blocks.shape[1]
-        bt = _pick_block(_round_up(t, 8), 128)
-        t_pad = _round_up(t, bt)
-        out = cyclic_encode(_pad_to(blocks, 1, t_pad), sup, coef,
-                            bt=bt, interpret=backend != "pallas")
-        return out[:, :t]
+        return encode_kernel(blocks, sup, coef, interpret=backend != "pallas")
     # reference and packed: the jnp gather-einsum oracle is already the
     # weight-omega O(omega) encoder
     return cyclic_encode_ref(blocks, sup, coef)
@@ -178,6 +223,10 @@ class CodedExecutor:
         self.packed: PackedShards | None = None
         self.cache: DecodeCache | None = None
         self._bsr = None            # lazy scipy BSR shards ("packed")
+        # calls per path taken: the backend name on the fast path,
+        # "reference" where a traced input fell back
+        self.calls: Counter = Counter()
+        self._calls_lock = threading.Lock()
         if self.backend != "reference":
             tile = 128 if self.backend == "pallas" else 8
             self.packed = pack_coded_blocks(np.asarray(self.coded),
@@ -204,7 +253,10 @@ class CodedExecutor:
         return self.backend != "pallas"
 
     def _fast_path(self, *vals) -> bool:
-        return self.backend != "reference" and _is_concrete(*vals)
+        fast = self.backend != "reference" and _is_concrete(*vals)
+        with self._calls_lock:
+            self.calls[self.backend if fast else "reference"] += 1
+        return fast
 
     # -- matvec: A^T x ----------------------------------------------------
 
@@ -240,17 +292,13 @@ class CodedExecutor:
         b = xb.shape[0]
         if self.backend in _KERNEL_BACKENDS:
             a_data, a_idx = packed.select_workers(plan.rows)
-            b_pad = _round_up(b, 8)
-            b_op = _pad_to(_pad_to(xb.T, 0, packed.t_pad), 1, b_pad)
-            bn = _pick_block(b_pad, 128)
-            y = bcsr_matmul(a_data, a_idx, b_op, bn=bn,
-                            interpret=self._interpret())
-            y = y.reshape(self.k, packed.c_pad * b_pad)
-            bp = _pick_block(y.shape[1], 512)
-            u = decode_matmul(plan.hinv_dev, y, bp=bp,
+            b_op = _pad_to(xb.T, 0, packed.t_pad)
+            y = worker_kernel(a_data, a_idx, b_op,
                               interpret=self._interpret())
-            u = u.reshape(self.k, packed.c_pad, b_pad)
-            u = u[:, : packed.c, :b]                    # drop padding
+            y = y.reshape(self.k, packed.c_pad * b)
+            u = decode_kernel(plan.hinv_dev, y, interpret=self._interpret())
+            u = u.reshape(self.k, packed.c_pad, b)
+            u = u[:, : packed.c]                        # drop padding
             out = jnp.moveaxis(u, 2, 0).reshape(b, -1)  # (b, k*c)
             return out[:, : self.r]
         # scipy BSR shards: nnz-tile-proportional worker products,
@@ -299,22 +347,15 @@ class CodedExecutor:
         cb = coded_b.shape[2]
         # stragglers' products are never computed: fastest-k only
         if self.backend in _KERNEL_BACKENDS:
-            cb_pad = _round_up(cb, 8)
             prods = []
             for i in plan.rows:
                 a_data, a_idx = packed.worker_view(int(i))
-                b_op = _pad_to(_pad_to(coded_b[int(i)], 0, packed.t_pad),
-                               1, cb_pad)
-                bn = _pick_block(cb_pad, 128)
-                prods.append(bcsr_matmul(a_data, a_idx, b_op, bn=bn,
-                                         interpret=self._interpret()))
-            y = jnp.stack(prods)[:, : packed.c, :cb]    # (k, ca, cb)
-            flat = y.reshape(self.k, -1)
-            p_pad = _round_up(flat.shape[1], 8)
-            bp = _pick_block(p_pad, 512)
-            u = decode_matmul(plan.hinv_dev, _pad_to(flat, 1, p_pad),
-                              bp=bp, interpret=self._interpret())
-            u = u[:, : flat.shape[1]]
+                b_op = _pad_to(coded_b[int(i)], 0, packed.t_pad)
+                prods.append(worker_kernel(a_data, a_idx, b_op,
+                                           interpret=self._interpret()))
+            y = jnp.stack(prods)[:, : packed.c]         # (k, ca, cb)
+            u = decode_kernel(plan.hinv_dev, y.reshape(self.k, -1),
+                              interpret=self._interpret())
             return u.reshape((self.k,) + y.shape[1:])
         shards = self._bsr_shards()
         b_np = np.asarray(coded_b, np.float32)
@@ -354,11 +395,7 @@ class CodedExecutor:
         ysub = jnp.asarray(y)[plan.rows].astype(jnp.float32)
         flat = ysub.reshape(self.k, -1)
         if self.backend in _KERNEL_BACKENDS:
-            p_pad = _round_up(flat.shape[1], 8)
-            bp = _pick_block(p_pad, 512)
-            u = decode_matmul(plan.hinv_dev, _pad_to(flat, 1, p_pad), bp=bp,
-                              interpret=self._interpret())
-            u = u[:, : flat.shape[1]]
+            u = decode_kernel(plan.hinv_dev, flat, interpret=self._interpret())
         else:
             u = plan.hinv_dev @ flat
         u = u.reshape((self.k,) + ysub.shape[1:])

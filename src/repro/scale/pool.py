@@ -155,7 +155,9 @@ class RemotePool(WorkerPool):
 
     ``launch(worker_id, port)`` is the deployment hook: start the
     remote process (ssh, container API, ...) that runs
-    ``python -m repro.cluster.worker --connect host:port --id N``.
+    ``python -m repro.cluster.worker --connect host:port --id N``,
+    with ``repro.cluster.worker_env()`` as its environment so the worker
+    stays off the accelerator.
     The pool picks the id, fires the launcher, then waits out the join
     handshake + shard catch-up; a launch whose dial never lands is
     torn down and retried under the shared ``RetryPolicy``.

@@ -1,49 +1,12 @@
-"""Suite-wide setup.
-
-* Makes ``src`` importable when pytest runs from the repo root without
-  PYTHONPATH (the tier-1 command sets it; direct IDE runs often don't).
-* If the optional ``hypothesis`` dependency is missing, installs the
-  deterministic fallback from ``tests/_hypothesis_compat.py`` under
-  ``sys.modules`` so the six property-test modules still collect and
-  run their seeded example sweeps instead of erroring out.
-"""
+"""Suite-wide setup: makes ``src`` importable when pytest runs from the
+repo root without PYTHONPATH (the tier-1 command sets it; direct IDE
+runs often don't)."""
 
 from __future__ import annotations
 
-import importlib.util
 import sys
-import types
 from pathlib import Path
 
-_HERE = Path(__file__).resolve().parent
-_SRC = str(_HERE.parent / "src")
+_SRC = str(Path(__file__).resolve().parent.parent / "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-
-def _load_compat():
-    spec = importlib.util.spec_from_file_location(
-        "_hypothesis_compat", _HERE / "_hypothesis_compat.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _compat = _load_compat()
-
-    _strategies = types.ModuleType("hypothesis.strategies")
-    _strategies.integers = _compat.strategies.integers
-    _strategies.lists = _compat.strategies.lists
-    _strategies.data = _compat.strategies.data
-
-    _shim = types.ModuleType("hypothesis")
-    _shim.given = _compat.given
-    _shim.settings = _compat.settings
-    _shim.strategies = _strategies
-    _shim.__is_repro_compat__ = True
-
-    sys.modules["hypothesis"] = _shim
-    sys.modules["hypothesis.strategies"] = _strategies

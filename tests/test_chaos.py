@@ -300,9 +300,13 @@ class TestChaosRuns:
         # of the three scripted kills actually fell their worker can
         # shift with scheduler noise -- assert the invariant instead:
         # resilience shrank below the configured s=2, and k follows
-        # the policy k' = min(k, n') (availability goes last)
+        # the policy k' = min(k, hosts') (availability goes last).  n'
+        # can exceed the host count: measurably uneven worker rates
+        # give the re-cut a capacity-weighted (hetero) layout
+        final = [ev for ev in res.events if ev["kind"] == "reencode"
+                 and ev["new_plan"] == res.final_plan["plan_id"]][-1]
         assert res.final_plan["n"] < 6
-        assert res.final_plan["k"] == min(4, res.final_plan["n"])
+        assert res.final_plan["k"] == min(4, len(final["hosts"]))
         assert res.final_plan["s"] < 2
 
     def test_recovery_latency_is_reported_per_fault_kind(self):

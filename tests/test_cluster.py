@@ -42,8 +42,10 @@ from repro.cluster import (
     resolve_transport,
     shard_plan,
     straggler_mask,
+    worker_env,
 )
 from repro.cluster.faults import from_spec
+from repro.cluster.transport.base import start_worker_process
 from repro.cluster.wire import (
     WIRE_VERSION,
     Heartbeat,
@@ -609,6 +611,35 @@ class TestFailStopAndTransports:
             got = np.asarray(cl.matvec(x, done))
         # same f32 BSR math on the far side of the pipe
         np.testing.assert_array_equal(got, want)
+
+
+def _put_jax_platforms(q):
+    q.put(os.environ.get("JAX_PLATFORMS"))
+
+
+class TestWorkerEnv:
+    """Worker processes start with JAX held to the CPU, so none can reach
+    for the accelerator its coordinator holds."""
+
+    def test_spawned_worker_starts_cpu_only(self, monkeypatch):
+        import multiprocessing as mp
+
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        ctx = mp.get_context("spawn")
+        q = ctx.Queue()
+        proc = ctx.Process(target=_put_jax_platforms, args=(q,))
+        start_worker_process(proc)
+        try:
+            assert q.get(timeout=60) == "cpu"
+        finally:
+            proc.join(timeout=60)
+        assert os.environ["JAX_PLATFORMS"] == "tpu"     # parent restored
+
+    def test_worker_env_for_subprocesses(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert worker_env()["JAX_PLATFORMS"] == "cpu"
+        assert worker_env({"PATH": "/bin"}) == {"PATH": "/bin",
+                                                 "JAX_PLATFORMS": "cpu"}
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ import pytest
 
 from repro.api import CodedFleet, compile_plan
 from repro.api.fleet import FleetDegraded, default_max_inflight
-from repro.cluster import ScriptedFaults, StragglerFaults
+from repro.cluster import ScriptedFaults, StragglerFaults, worker_env
 
 TOL = dict(rtol=5e-3, atol=5e-3)
 
@@ -397,10 +397,11 @@ class TestRemoteWorker:
         probe.bind(("127.0.0.1", 0))
         port = probe.getsockname()[1]
         probe.close()
-        env = {**os.environ,
-               "PYTHONPATH": os.pathsep.join(
-                   ["src"] + os.environ.get("PYTHONPATH", "").split(
-                       os.pathsep)).rstrip(os.pathsep)}
+        env = worker_env({
+            **os.environ,
+            "PYTHONPATH": os.pathsep.join(
+                ["src"] + os.environ.get("PYTHONPATH", "").split(
+                    os.pathsep)).rstrip(os.pathsep)})
         procs = [subprocess.Popen(
             [sys.executable, "-m", "repro.cluster.worker",
              "--connect", f"127.0.0.1:{port}", "--id", str(w)],
@@ -648,6 +649,10 @@ class TestElasticMembership:
             # seeded EWMAs quantize to 1..levels, proportional to the
             # fastest; unmeasured workers get the median live rate
             fleet._rate.update({0: 4.0, 1: 1.0, 2: 2.0})
+            fleet._rate_n.update({0: 1, 1: 1, 2: 1})
+            # one result per worker is arrival-order noise, not a speed
+            assert fleet.worker_capacities([0, 1, 2]) == [1, 1, 1]
+            fleet._rate_n.update({0: 3, 1: 3, 2: 3})
             assert fleet.worker_capacities([0, 1, 2]) == [4, 1, 2]
             assert fleet.worker_capacities([0, 1, 2, 3]) == [4, 1, 2, 2]
 
@@ -780,12 +785,15 @@ class TestGracefulDegradation:
         A, _, xs = operands
         plan = compile_plan(A, scheme="proposed", n=6, s=2,
                             backend="packed")
-        with CodedFleet(6, faults=FailStop({0: 0}), max_inflight=1,
-                        microbatch=False) as fleet:
+        with CodedFleet(6, faults=FailStop({0: 0, 1: 0, 2: 0}),
+                        max_inflight=1, microbatch=False) as fleet:
             h = fleet.attach(plan)
-            # round 1 kills worker 0 -> the plan re-encodes once its
+            # round 1 kills workers 0-2 -> the plan re-encodes once its
             # rounds drain; the queued explicit-mask call was built in
-            # the old version's task coordinates and cannot be rebuilt
+            # the old version's task coordinates and cannot be rebuilt.
+            # The 3 survivors are fewer than k=4, so round 1 answers only
+            # after the deaths are seen, and the queued call cannot
+            # start on the old version first
             f1 = h.submit_matvec(xs[0])
             f2 = h.submit_matvec(xs[1], np.ones(6, bool))
             np.testing.assert_allclose(np.asarray(f1.result()),

@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from repro.api import CodedFleet, compile_plan
+from repro.cluster import worker_env
 from repro.obs import Tracer
 from repro.scale import (
     Autoscaler,
@@ -395,10 +396,11 @@ class TestRemotePool:
         port = probe.getsockname()[1]
         probe.close()
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = {**os.environ,
-               "PYTHONPATH": os.pathsep.join(
-                   ["src"] + os.environ.get("PYTHONPATH", "").split(
-                       os.pathsep)).rstrip(os.pathsep)}
+        env = worker_env({
+            **os.environ,
+            "PYTHONPATH": os.pathsep.join(
+                ["src"] + os.environ.get("PYTHONPATH", "").split(
+                    os.pathsep)).rstrip(os.pathsep)})
         procs = []
 
         def launch(worker_id, port_):

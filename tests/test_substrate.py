@@ -1,5 +1,7 @@
 """Integration tests: data pipeline, optimizer, trainer loop,
-checkpoint/restart, coded layer, serving engine."""
+checkpoint/restart, coded layer, serving engine, compile cache."""
+
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +10,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.data import DataConfig, SyntheticTokens, make_pipeline
+from repro.launch.cache import CHECKOUT_CACHE_DIR, init_compile_cache
 from repro.models import build_model
 from repro.optim import AdamWConfig, CompressionConfig, apply_updates, init_state
 from repro.parallel.coded_layer import CodedLinear
@@ -214,3 +217,24 @@ class TestServeEngine:
             out = eng.coded_logits(hidden)
             np.testing.assert_allclose(np.asarray(out), ref,
                                        rtol=5e-3, atol=5e-3)
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_cache_dir(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield before
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_outside_directory_left_alone(self, monkeypatch,
+                                          restore_cache_dir):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert init_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == restore_cache_dir
+
+    def test_default_is_the_checkout(self, monkeypatch, restore_cache_dir):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert init_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR == (
+            Path(__file__).resolve().parents[1] / ".jax_cache")
