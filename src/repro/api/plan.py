@@ -42,6 +42,7 @@ from ..core.assignment import MMScheme, MVScheme
 from ..core.coded_matmul import fastest_k_rows, split_block_columns
 from ..core.decoding import system_matrix
 from ..core.encoding import mm_encoding_matrices, mv_encoding_matrix
+from ..obs.trace import default_tracer, optional_span, traced_call
 from ..runtime import (
     CodedExecutor,
     DecodeCache,
@@ -51,6 +52,9 @@ from ..runtime import (
 )
 from .backends import choose_backend
 from .schemes import make_scheme
+
+
+_PLAN = {"cat": "plan", "track": "plan"}
 
 
 def _match_dtype(coded, A):
@@ -90,6 +94,10 @@ class CodedPlan:
     # array reference, not a copy -- the caller's weights stay the
     # single allocation
     _A: object | None = field(default=None, repr=False)
+    _tracer: object | None = field(default=None, init=False, repr=False)
+
+    def __post_init__(self):
+        self._tracer = default_tracer()
 
     # -- introspection ----------------------------------------------------
 
@@ -166,7 +174,8 @@ class CodedPlan:
         if self.executor is None:
             raise ValueError("plan compiled without an operand; pass A to "
                              "compile_plan for matvec")
-        return self.executor.matvec(x, self._task_done(done))
+        return traced_call(self._tracer, "plan.matvec", self.executor.matvec,
+                           x, self._task_done(done), **_PLAN)
 
     def matmat(self, B, done=None):
         """A^T B through the paired-encode pipeline; returns (r, w)."""
@@ -176,17 +185,29 @@ class CodedPlan:
         if self.executor is None:
             raise ValueError("plan compiled without an operand; pass A to "
                              "compile_plan for matmat")
-        sch = self.scheme
-        w = B.shape[1]
-        blocks_b = split_block_columns(B, sch.k_B)
-        if self.backend == "reference" or not _is_concrete(B, done):
-            coded_b = jnp.einsum("nk,ktc->ntc",
-                                 jnp.asarray(self._rb, B.dtype), blocks_b)
-        else:
-            coded_b = encode_blocks(blocks_b, self._sup_b, self._coef_b,
-                                    self.backend)
+        return traced_call(self._tracer, "plan.matmat", self._matmat, B,
+                           done, **_PLAN)
+
+    def _matmat(self, B, done):
+        tr = self._tracer
+        coded_b = traced_call(tr, "plan.encode_b", self._encode_b, B, done,
+                              **_PLAN)
         u = self.executor.matmat(coded_b, done)      # (k, ca, cb)
-        ka, kb = sch.k_A, sch.k_B
+        return traced_call(tr, "executor.output", self._assemble, u,
+                           B.shape[1], **_PLAN)
+
+    def _encode_b(self, B, done):
+        """B's k_B block-columns, encoded into the n coded B shards."""
+        blocks_b = split_block_columns(B, self.scheme.k_B)
+        if self.backend == "reference" or not _is_concrete(B, done):
+            return jnp.einsum("nk,ktc->ntc",
+                              jnp.asarray(self._rb, B.dtype), blocks_b)
+        return encode_blocks(blocks_b, self._sup_b, self._coef_b,
+                             self.backend)
+
+    def _assemble(self, u, w):
+        """Decoded unknowns (k, ca, cb) -> A^T B (r, w)."""
+        ka, kb = self.scheme.k_A, self.scheme.k_B
         ca, cb = u.shape[1], u.shape[2]
         out = u.reshape(ka, kb, ca, cb).transpose(0, 2, 1, 3)
         return out.reshape(ka * ca, kb * cb)[: self.r, : w]
@@ -307,8 +328,6 @@ def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
     ``REPRO_CODED_BACKEND`` env var overrides everything, including
     auto.  Without ``A`` the plan is aggregation-only.
     """
-    from ..obs.trace import default_tracer  # noqa: PLC0415 (cycle-free)
-
     tr = default_tracer()
     t0 = time.perf_counter() if tr is not None else 0.0
     if isinstance(scheme, (MVScheme, MMScheme)):
@@ -339,53 +358,40 @@ def _attach_operand(plan: CodedPlan, A, resolved: str) -> None:
 
     Shared by initial compilation and ``plan.retune`` -- re-tuning is
     literally re-running this attachment against the drifted operand.
+    The executor packs the coded shards (``plan.pack``).
     """
-    from ..obs.trace import default_tracer  # noqa: PLC0415 (cycle-free)
-
     if A.ndim != 2:
         raise ValueError(f"operand must be 2-D (t, r), got {A.shape}")
-    tr = default_tracer()
-    if tr is not None:
-        with tr.span("plan.encode", cat="plan", track="plan",
-                     kind=plan.kind, backend=resolved,
-                     shape=list(A.shape)):
-            _attach_operand_inner(plan, A, resolved)
-        return
-    _attach_operand_inner(plan, A, resolved)
-
-
-def _attach_operand_inner(plan: CodedPlan, A, resolved: str) -> None:
-    sch, G, seed = plan.scheme, plan.G, plan.seed
-    cache_size = plan.cache_size
-    if plan.kind == "mv":
-        R = mv_encoding_matrix(sch, seed)
-        blocks = split_block_columns(A, sch.k_A)
-        if resolved == "reference":
-            coded = jnp.einsum("nk,ktc->ntc", jnp.asarray(R, A.dtype),
-                               blocks)
+    sch, tr = plan.scheme, plan._tracer
+    with optional_span(tr, "plan.encode", cat="plan", track="plan",
+                       kind=plan.kind, backend=resolved,
+                       shape=list(A.shape)):
+        if plan.kind == "mv":
+            R = mv_encoding_matrix(sch, plan.seed)
+            coded = _encode(A, sch.k_A, sch.supports, R, resolved)
+            k = sch.k_A
         else:
-            sup, coef = support_tables(sch.supports, R)
-            coded = encode_blocks(blocks, sup, coef, resolved)
-        coded = _match_dtype(coded, A)
-        plan.executor = CodedExecutor(
-            coded, jnp.asarray(G, jnp.float32), sch.k_A, A.shape[1],
-            backend=resolved, cache_size=cache_size)
-    else:
-        ra, rb = mm_encoding_matrices(sch, seed)
-        blocks_a = split_block_columns(A, sch.k_A)
-        if resolved == "reference":
-            coded_a = jnp.einsum("nk,ktc->ntc", jnp.asarray(ra, A.dtype),
-                                 blocks_a)
-            plan._sup_b = plan._coef_b = None
-        else:
-            sup_a, coef_a = support_tables(sch.supports_A, ra)
-            coded_a = encode_blocks(blocks_a, sup_a, coef_a, resolved)
-            plan._sup_b, plan._coef_b = support_tables(sch.supports_B, rb)
-        plan._rb = rb
-        plan.executor = CodedExecutor(
-            _match_dtype(coded_a, A), jnp.asarray(G, jnp.float32),
-            sch.k, A.shape[1], backend=resolved, cache_size=cache_size)
+            ra, rb = mm_encoding_matrices(sch, plan.seed)
+            coded = _encode(A, sch.k_A, sch.supports_A, ra, resolved)
+            k = sch.k
+            plan._rb = rb
+            plan._sup_b, plan._coef_b = (
+                (None, None) if resolved == "reference"
+                else support_tables(sch.supports_B, rb))
+    plan.executor = CodedExecutor(
+        _match_dtype(coded, A), jnp.asarray(plan.G, jnp.float32), k,
+        A.shape[1], backend=resolved, cache_size=plan.cache_size)
     plan.r = A.shape[1]
     if _is_concrete(A):
         plan._A = A
-        plan.prewarm()
+        with optional_span(tr, "plan.prewarm", cat="plan", track="plan"):
+            plan.prewarm()
+
+
+def _encode(A, k: int, supports, R, resolved: str):
+    """A's k block-columns, encoded by R into the n coded shards."""
+    blocks = split_block_columns(A, k)
+    if resolved == "reference":
+        return jnp.einsum("nk,ktc->ntc", jnp.asarray(R, A.dtype), blocks)
+    sup, coef = support_tables(supports, R)
+    return encode_blocks(blocks, sup, coef, resolved)

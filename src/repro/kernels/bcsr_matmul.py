@@ -80,6 +80,7 @@ def bcsr_matmul(a_data: jnp.ndarray, a_idx: jnp.ndarray, b: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((mb * bm, n), jnp.float32),
         interpret=interpret,
+        name="bcsr_matmul",
     )
     return kernel(a_idx, a_data, b)
 

@@ -65,6 +65,7 @@ def cyclic_encode(blocks: jnp.ndarray, sup: jnp.ndarray, coef: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((n, t, c), jnp.float32),
         interpret=interpret,
+        name="cyclic_encode",
     )
     return kernel(sup, coef, blocks)
 

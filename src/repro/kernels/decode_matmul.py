@@ -46,6 +46,7 @@ def decode_matmul(hinv: jnp.ndarray, y: jnp.ndarray, *, bp: int = 512,
         out_specs=pl.BlockSpec((k, bp), lambda pp: (0, pp)),
         out_shape=jax.ShapeDtypeStruct((k, p), jnp.float32),
         interpret=interpret,
+        name="decode_matmul",
     )
     return kernel(hinv.astype(jnp.float32), y.astype(jnp.float32))
 

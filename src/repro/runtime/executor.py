@@ -44,6 +44,7 @@ from ..kernels.bcsr_matmul import bcsr_matmul
 from ..kernels.cyclic_encode import cyclic_encode
 from ..kernels.decode_matmul import decode_matmul
 from ..kernels.ref import cyclic_encode_ref
+from ..obs.trace import default_tracer, optional_span, traced_call
 from .decode_cache import DecodeCache
 from .pack import PackedShards, _round_up, bsr_shards, pack_coded_blocks
 
@@ -196,6 +197,11 @@ def encode_blocks(blocks, sup, coef, backend: str | None = None) -> jnp.ndarray:
 # The executor
 # ---------------------------------------------------------------------------
 
+# the per-call stages of the kernel path, each a span where tracing is on
+SELECT, WORKER, DECODE, OUTPUT = ("executor.select", "executor.worker",
+                                  "executor.decode", "executor.output")
+_STAGE = {"cat": "executor", "track": "executor"}
+
 
 class CodedExecutor:
     """Backend-dispatched encode / worker-compute / decode engine.
@@ -227,10 +233,13 @@ class CodedExecutor:
         # "reference" where a traced input fell back
         self.calls: Counter = Counter()
         self._calls_lock = threading.Lock()
+        self._tracer = default_tracer()
         if self.backend != "reference":
             tile = 128 if self.backend == "pallas" else 8
-            self.packed = pack_coded_blocks(np.asarray(self.coded),
-                                            bk or tile, bm or tile)
+            with optional_span(self._tracer, "plan.pack", cat="plan",
+                               track="plan"):
+                self.packed = pack_coded_blocks(np.asarray(self.coded),
+                                                bk or tile, bm or tile)
             self.cache = DecodeCache(np.asarray(self.G), k,
                                      maxsize=cache_size)
 
@@ -287,20 +296,11 @@ class CodedExecutor:
     def _matvec_packed(self, xb, done):
         if done is None:
             done = np.ones(self.n, dtype=bool)
+        if self.backend in _KERNEL_BACKENDS:
+            return self._matvec_kernel(xb, done)
         plan = self.cache.plan(done)
         packed = self.packed
         b = xb.shape[0]
-        if self.backend in _KERNEL_BACKENDS:
-            a_data, a_idx = packed.select_workers(plan.rows)
-            b_op = _pad_to(xb.T, 0, packed.t_pad)
-            y = worker_kernel(a_data, a_idx, b_op,
-                              interpret=self._interpret())
-            y = y.reshape(self.k, packed.c_pad * b)
-            u = decode_kernel(plan.hinv_dev, y, interpret=self._interpret())
-            u = u.reshape(self.k, packed.c_pad, b)
-            u = u[:, : packed.c]                        # drop padding
-            out = jnp.moveaxis(u, 2, 0).reshape(b, -1)  # (b, k*c)
-            return out[:, : self.r]
         # scipy BSR shards: nnz-tile-proportional worker products,
         # stragglers (and zero tiles) never touched; stays host-side
         # numpy end-to-end to keep eager-dispatch overhead off the
@@ -313,6 +313,69 @@ class CodedExecutor:
         u = u.reshape(self.k, packed.c_pad, b)[:, : packed.c]
         out = np.moveaxis(u, 2, 0).reshape(b, -1)[:, : self.r]
         return jnp.asarray(out)
+
+    # -- the kernel path's stages ------------------------------------------
+
+    def _matvec_kernel(self, xb, done):
+        tr = self._tracer
+        plan, a_data, a_idx = traced_call(tr, SELECT, self._select, done,
+                                          **_STAGE)
+        y = traced_call(tr, WORKER, self._matvec_worker, a_data, a_idx, xb,
+                        **_STAGE)
+        u = traced_call(tr, DECODE, self._decode, plan, y, **_STAGE)
+        return traced_call(tr, OUTPUT, self._matvec_output, u, xb.shape[0],
+                           **_STAGE)
+
+    def _select(self, done):
+        """The mask's decode plan and the selected workers' tiles, still
+        fused along the output-block axis."""
+        plan = self.cache.plan(done)
+        return (plan,) + self.packed.select_workers(plan.rows)
+
+    def _matvec_worker(self, a_data, a_idx, xb):
+        b_op = _pad_to(xb.T, 0, self.packed.t_pad)
+        return worker_kernel(a_data, a_idx, b_op, interpret=self._interpret())
+
+    def _decode(self, plan, y):
+        return decode_kernel(plan.hinv_dev, y.reshape(self.k, -1),
+                             interpret=self._interpret())
+
+    def _matvec_output(self, u, b):
+        packed = self.packed
+        u = u.reshape(self.k, packed.c_pad, b)
+        u = u[:, : packed.c]                            # drop padding
+        out = jnp.moveaxis(u, 2, 0).reshape(b, -1)      # (b, k*c)
+        return out[:, : self.r]
+
+    def _matmat_kernel(self, coded_b, done):
+        tr = self._tracer
+        interpret = self._interpret()
+        plan = traced_call(tr, SELECT, self.cache.plan, done, **_STAGE)
+        # stragglers' products are never computed: fastest-k only
+        if tr is None:
+            prods = [worker_kernel(*self._worker_operands(int(i), coded_b),
+                                   interpret=interpret) for i in plan.rows]
+        else:
+            prods = []
+            for i in plan.rows:
+                with tr.span(SELECT, **_STAGE):
+                    ops = self._worker_operands(int(i), coded_b)
+                with tr.span(WORKER, **_STAGE):
+                    prods.append(worker_kernel(*ops, interpret=interpret))
+        u = traced_call(tr, DECODE, self._matmat_decode, plan, prods,
+                        **_STAGE)
+        return traced_call(tr, OUTPUT, u.reshape,
+                           (self.k, self.packed.c, coded_b.shape[2]),
+                           **_STAGE)
+
+    def _worker_operands(self, i: int, coded_b):
+        """Worker ``i``'s tiles and its coded B shard, padded."""
+        a_data, a_idx = self.packed.worker_view(i)
+        return a_data, a_idx, _pad_to(coded_b[i], 0, self.packed.t_pad)
+
+    def _matmat_decode(self, plan, prods):
+        y = jnp.stack(prods)[:, : self.packed.c]        # (k, ca, cb)
+        return self._decode(plan, y)
 
     # -- matmat: per-worker A_i^T B_i, decoded unknowns --------------------
 
@@ -342,21 +405,11 @@ class CodedExecutor:
     def _matmat_packed(self, coded_b, done):
         if done is None:
             done = np.ones(self.n, dtype=bool)
+        if self.backend in _KERNEL_BACKENDS:
+            return self._matmat_kernel(coded_b, done)
         plan = self.cache.plan(done)
         packed = self.packed
         cb = coded_b.shape[2]
-        # stragglers' products are never computed: fastest-k only
-        if self.backend in _KERNEL_BACKENDS:
-            prods = []
-            for i in plan.rows:
-                a_data, a_idx = packed.worker_view(int(i))
-                b_op = _pad_to(coded_b[int(i)], 0, packed.t_pad)
-                prods.append(worker_kernel(a_data, a_idx, b_op,
-                                           interpret=self._interpret()))
-            y = jnp.stack(prods)[:, : packed.c]         # (k, ca, cb)
-            u = decode_kernel(plan.hinv_dev, y.reshape(self.k, -1),
-                              interpret=self._interpret())
-            return u.reshape((self.k,) + y.shape[1:])
         shards = self._bsr_shards()
         b_np = np.asarray(coded_b, np.float32)
         b_op = np.zeros((self.k, packed.t_pad, cb), np.float32)

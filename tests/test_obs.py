@@ -320,3 +320,211 @@ class TestDualClockLogs:
                 "replica", "endpoint"} <= set(e)
         assert abs(e["t"] - time.time()) < 5.0
         assert abs(e["t_mono"] - time.perf_counter()) < 5.0
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink, counters, and the coded plan path's own spans
+# ---------------------------------------------------------------------------
+
+
+def _profile(tmp_path, body):
+    """Runs ``body()`` under the JAX profiler at the benchmark's options
+    and returns the host plane's events as (name, start, end)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def _count_annotations(monkeypatch) -> list[str]:
+    """The names of the profiler annotations the tracer opens from now on."""
+    import repro.obs.trace as trace_mod
+
+    opened = []
+
+    class Counting:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Counting)
+    return opened
+
+
+class TestProfilerSink:
+    def test_spans_land_in_the_profiler_trace_nested(self, tmp_path):
+        tr = Tracer(capacity=32)
+
+        def body():
+            with tr.span("outer.span"):
+                with tr.span("inner.span"):
+                    time.sleep(0.002)
+
+        events = _profile(tmp_path, body)
+        (outer,) = [e for e in events if e[0] == "outer.span"]
+        (inner,) = [e for e in events if e[0] == "inner.span"]
+        assert _inside(inner, outer) and inner[2] - inner[1] >= 1.5e6
+        # the ring keeps both as before
+        assert [e["name"] for e in tr.events()] == ["inner.span",
+                                                    "outer.span"]
+
+    def test_complete_stays_in_the_ring(self, tmp_path):
+        tr = Tracer(capacity=8)
+
+        def body():
+            t0 = time.perf_counter()
+            tr.complete("past.span", t0 - 1.0, t0)
+
+        events = _profile(tmp_path, body)
+        assert not [e for e in events if e[0] == "past.span"]
+        assert [e["name"] for e in tr.events()] == ["past.span"]
+
+    def test_no_tracer_opens_no_annotation(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+
+        opened = _count_annotations(monkeypatch)
+        assert trace_mod.traced_call(None, "x.stage", lambda a: a + 1, 1) == 2
+        with trace_mod.optional_span(None, "x.setup"):
+            pass
+        assert opened == []
+        tr = Tracer(capacity=8)
+        assert trace_mod.traced_call(tr, "x.stage", lambda a: a + 1, 1) == 2
+        with trace_mod.optional_span(tr, "x.setup"):
+            pass
+        assert opened == ["x.stage", "x.setup"]
+
+
+class TestCounters:
+    def test_count_and_snapshot(self):
+        tr = Tracer(capacity=8)
+        tr.count("a.b")
+        tr.count("a.b", 4)
+        snap = tr.counters()
+        tr.count("a.b")
+        assert snap["a.b"] == 5 and tr.counters()["a.b"] == 6
+        tr.clear()                          # the ring, not the counters
+        assert tr.counters()["a.b"] == 6
+
+    def test_lowerings_count_a_fresh_jit_once(self):
+        import jax
+        from repro.obs import LOWERINGS
+
+        x = jnp.arange(7.0)
+        x.block_until_ready()
+        tr = Tracer(capacity=8)
+        f = jax.jit(lambda v: v * 3.0 + 1.0)
+        before = tr.counters().get(LOWERINGS, 0)
+        f(x).block_until_ready()
+        first = tr.counters()[LOWERINGS]
+        f(x).block_until_ready()
+        assert first - before == 1
+        assert tr.counters()[LOWERINGS] == first     # the repeat lowers none
+
+
+@pytest.fixture
+def process_tracer(monkeypatch):
+    """``REPRO_TRACE=1`` with a fresh process tracer."""
+    import repro.obs.trace as trace_mod
+
+    tr = Tracer(capacity=4096)
+    monkeypatch.setenv("REPRO_TRACE", "1")
+    monkeypatch.setattr(trace_mod, "_GLOBAL", tr)
+    return tr
+
+
+def _by_name(events, name):
+    return [(e["name"], e["t"], e["t"] + e["dur"]) for e in events
+            if e["name"] == name]
+
+
+class TestPlanSpans:
+    """``compile_plan`` and the kernel path of one MV and one MM call
+    record the program's spans (``PROGRAM_SPANS``), children inside their
+    parents."""
+
+    def test_matvec_spans(self, process_tracer):
+        from repro.obs import PROGRAM_SPANS
+
+        rng = np.random.default_rng(3)
+        A = jnp.asarray(block_sparse(rng, 128, 96, 0.5))
+        plan = compile_plan(A, scheme="proposed", n=6, s=2,
+                            backend="pallas-interpret")
+        setup = process_tracer.events()
+        (compile_span,) = _by_name(setup, "plan.compile")
+        for name in ("plan.encode", "plan.pack", "plan.prewarm"):
+            (span,) = _by_name(setup, name)
+            assert _inside(span, compile_span), name
+        process_tracer.clear()
+        done = np.ones(6, bool)
+        done[[1, 4]] = False
+        x = jnp.asarray(rng.standard_normal((4, 128)), jnp.float32)
+        for _ in range(2):
+            plan.matvec(x, done).block_until_ready()
+        events = process_tracer.events()
+        calls = _by_name(events, "plan.matvec")
+        assert len(calls) == 2
+        for stage in ("executor.select", "executor.worker",
+                      "executor.decode", "executor.output"):
+            spans = _by_name(events, stage)
+            assert len(spans) == 2, stage
+            assert all(_inside(s, c) for s, c in zip(spans, calls)), stage
+        assert {e["name"] for e in setup + events} <= set(PROGRAM_SPANS)
+
+    def test_matmat_spans(self, process_tracer):
+        rng = np.random.default_rng(4)
+        A = jnp.asarray(block_sparse(rng, 96, 64, 0.5))
+        plan = compile_plan(A, scheme="proposed", n=8, k_A=2, k_B=2,
+                            backend="pallas-interpret")
+        process_tracer.clear()
+        B = jnp.asarray(rng.standard_normal((96, 40)), jnp.float32)
+        done = np.ones(8, bool)
+        done[[0, 5]] = False
+        plan.matmat(B, done).block_until_ready()
+        events = process_tracer.events()
+        (call,) = _by_name(events, "plan.matmat")
+        k = plan.k
+        workers = _by_name(events, "executor.worker")
+        assert len(workers) == k
+        # the decode-cache lookup, then one per worker
+        assert len(_by_name(events, "executor.select")) == k + 1
+        for name in ("plan.encode_b", "executor.decode", "executor.select",
+                     "executor.worker", "executor.output"):
+            assert all(_inside(s, call) for s in _by_name(events, name)), name
+        # the executor's output reshape and the plan's assembly
+        assert len(_by_name(events, "executor.output")) == 2
+
+    def test_untraced_plan_records_nothing(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+
+        monkeypatch.delenv("REPRO_TRACE", raising=False)
+        spy = Tracer(capacity=8)
+        monkeypatch.setattr(trace_mod, "_GLOBAL", spy)
+        opened = _count_annotations(monkeypatch)
+        rng = np.random.default_rng(5)
+        A = jnp.asarray(block_sparse(rng, 128, 96, 0.5))
+        plan = compile_plan(A, scheme="proposed", n=6, s=2,
+                            backend="pallas-interpret")
+        assert plan._tracer is None and plan.executor._tracer is None
+        plan.matvec(jnp.ones((2, 128), jnp.float32)).block_until_ready()
+        assert len(spy) == 0 and opened == []
