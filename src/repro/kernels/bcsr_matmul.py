@@ -14,8 +14,12 @@ K-tiles of A into a packed array with their K-block indices.  The
 kernel walks grid (Mb, Nb, J); the B tile for slot j is selected with a
 *scalar-prefetched* index (``PrefetchScalarGridSpec``), i.e. a
 block-table indirection in the same spirit as paged attention -- the
-TPU analogue of the CSR pointer chase.  Accumulation happens in the
-f32 output tile in VMEM across the innermost grid dimension.
+TPU analogue of the CSR pointer chase.  A second scalar-prefetched
+table picks, per output block-column, the block-row of A it reads, so
+a caller computes any subset of a resident packed operand's rows (the
+fastest-k workers') in place, with no gathered copy.  Accumulation
+happens in the f32 output tile in VMEM across the innermost grid
+dimension.
 
 VMEM budget per step (defaults bk=bm=bn=128, f32):
   A tile 64 KiB + B tile 64 KiB + C tile 64 KiB << 16 MiB VMEM.
@@ -32,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _bcsr_matmul_kernel(idx_ref, a_ref, b_ref, c_ref):
+def _bcsr_matmul_kernel(blocks_ref, idx_ref, a_ref, b_ref, c_ref):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -49,15 +53,26 @@ def _bcsr_matmul_kernel(idx_ref, a_ref, b_ref, c_ref):
 
 
 def bcsr_matmul(a_data: jnp.ndarray, a_idx: jnp.ndarray, b: jnp.ndarray,
-                *, bn: int = 128, interpret: bool = False) -> jnp.ndarray:
-    """C = A^T @ B from packed block-sparse A.
+                *, blocks: jnp.ndarray | None = None, bn: int = 128,
+                interpret: bool = False) -> jnp.ndarray:
+    """C = A^T @ B from packed block-sparse A, read through a block-row
+    table.
 
-    a_data : (Mb, J, bk, bm)  packed nonzero tiles (zero-padded slots)
-    a_idx  : (Mb, J) int32    K-block index per slot
-    b      : (K, N)           dense right operand
+    a_data : (Mb_in, J, bk, bm)  packed nonzero tiles (zero-padded slots)
+    a_idx  : (Mb, J) int32       K-block index per slot of each row read
+    b      : (K, N)              dense right operand
+    blocks : (Mb,) int32         output block-column m reads a_data's
+                                 block-row blocks[m]; None is the
+                                 identity (Mb = Mb_in)
     Returns C : (Mb*bm, N) float32.
     """
-    mb, j, bk, bm = a_data.shape
+    _, j, bk, bm = a_data.shape
+    mb = a_idx.shape[0]
+    if blocks is None:
+        blocks = jnp.arange(a_data.shape[0], dtype=jnp.int32)
+    if blocks.shape != (mb,) or a_idx.shape[1] != j:
+        raise ValueError(f"blocks {blocks.shape} and a_idx {a_idx.shape} "
+                         f"do not fit a_data {a_data.shape}")
     k, n = b.shape
     if k % bk:
         raise ValueError(f"K={k} not a multiple of bk={bk}")
@@ -70,19 +85,22 @@ def bcsr_matmul(a_data: jnp.ndarray, a_idx: jnp.ndarray, b: jnp.ndarray,
     kernel = pl.pallas_call(
         _bcsr_matmul_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, bk, bm), lambda m, nn, jj, idx: (m, jj, 0, 0)),
-                pl.BlockSpec((bk, bn), lambda m, nn, jj, idx: (idx[m, jj], nn)),
+                pl.BlockSpec((1, 1, bk, bm),
+                             lambda m, nn, jj, blk, idx: (blk[m], jj, 0, 0)),
+                pl.BlockSpec((bk, bn),
+                             lambda m, nn, jj, blk, idx: (idx[m, jj], nn)),
             ],
-            out_specs=pl.BlockSpec((bm, bn), lambda m, nn, jj, idx: (m, nn)),
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda m, nn, jj, blk, idx: (m, nn)),
         ),
         out_shape=jax.ShapeDtypeStruct((mb * bm, n), jnp.float32),
         interpret=interpret,
         name="bcsr_matmul",
     )
-    return kernel(a_idx, a_data, b)
+    return kernel(blocks, a_idx, a_data, b)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))

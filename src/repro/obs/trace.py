@@ -67,8 +67,9 @@ PROGRAM_SPANS = (
     "plan.matvec",        # one product, from the plan's entry to return
     "plan.matmat",
     "plan.encode_b",      # matmat: split and encode of B
-    "executor.select",    # decode-cache lookup and the selected workers'
-                          # tiles (matmat: one a worker, with its B shard)
+    "executor.select",    # decode-cache lookup (a new pattern: its plan
+                          # and block-row table); matmat: one a worker,
+                          # with its B shard
     "executor.worker",    # worker-kernel launch (matvec: x padded first)
     "executor.decode",    # decode-kernel launch and its operand
     "executor.output",    # reshapes and slices to the output's layout

@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from collections.abc import Callable
 from dataclasses import dataclass
+from typing import Any
 
 import jax.numpy as jnp
 import numpy as np
@@ -35,17 +37,22 @@ class DecodePlan:
     rows: np.ndarray           # (k,) fastest-k task rows (host ints)
     hinv: np.ndarray           # (k, k) f32 inverse of G[rows] (host)
     hinv_dev: jnp.ndarray      # same, device-resident for the kernels
+    tables: Any = None         # the owner's per-pattern operands (the
+                               # executor's block-row table), if any
 
 
 class DecodeCache:
     """LRU cache of ``DecodePlan`` keyed on the done mask."""
 
-    def __init__(self, G, k: int, maxsize: int = 64):
+    def __init__(self, G, k: int, maxsize: int = 64,
+                 tables: Callable[[np.ndarray], Any] | None = None):
         self._G = np.asarray(G, dtype=np.float64)
         if self._G.shape[1] != k:
             raise ValueError(f"G has {self._G.shape[1]} unknowns, expected {k}")
         self.k = k
         self.maxsize = maxsize
+        # built from a plan's rows on its miss, kept and evicted with it
+        self._tables = tables
         self._plans: OrderedDict[bytes, DecodePlan] = OrderedDict()
         self.hits = 0
         self.misses = 0   # == number of host-side k x k inversions run
@@ -75,7 +82,9 @@ class DecodeCache:
                 f"only {rows.shape[0]} tasks done, need k={self.k}")
         hinv = np.linalg.inv(self._G[rows]).astype(np.float32)
         plan = DecodePlan(key=key, rows=rows, hinv=hinv,
-                          hinv_dev=jnp.asarray(hinv))
+                          hinv_dev=jnp.asarray(hinv),
+                          tables=None if self._tables is None
+                          else self._tables(rows))
         with self._lock:
             self._plans[key] = plan
             self.misses += 1

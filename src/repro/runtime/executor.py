@@ -46,7 +46,8 @@ from ..kernels.decode_matmul import decode_matmul
 from ..kernels.ref import cyclic_encode_ref
 from ..obs.trace import default_tracer, optional_span, traced_call
 from .decode_cache import DecodeCache
-from .pack import PackedShards, _round_up, bsr_shards, pack_coded_blocks
+from .pack import (BlockRows, PackedShards, _round_up, bsr_shards,
+                   pack_coded_blocks)
 
 ENV_BACKEND = "REPRO_CODED_BACKEND"
 
@@ -135,11 +136,19 @@ def encode_kernel(blocks, sup, coef, *, interpret: bool = False):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def worker_kernel(a_data, a_idx, b, *, interpret: bool = False):
-    """``bcsr_matmul`` C = A^T b for b (t_pad, N), N padded to its tile."""
+    """``bcsr_matmul`` C = A^T b for b (t_pad, N), N padded to its tile.
+
+    ``a_idx`` is either the K-index table of every block-row of
+    ``a_data``, or a ``BlockRows`` table naming the rows to read in
+    place; the table is an argument, so a new one never recompiles.
+    """
+    blocks = None
+    if isinstance(a_idx, BlockRows):
+        blocks, a_idx = a_idx
     n = b.shape[1]
     bn, n_pad = lane_tile(n, 128)
-    out = bcsr_matmul(a_data, a_idx, _pad_to(b, 1, n_pad), bn=bn,
-                      interpret=interpret)
+    out = bcsr_matmul(a_data, a_idx, _pad_to(b, 1, n_pad), blocks=blocks,
+                      bn=bn, interpret=interpret)
     return out[:, :n]
 
 
@@ -200,6 +209,8 @@ def encode_blocks(blocks, sup, coef, backend: str | None = None) -> jnp.ndarray:
 # the per-call stages of the kernel path, each a span where tracing is on
 SELECT, WORKER, DECODE, OUTPUT = ("executor.select", "executor.worker",
                                   "executor.decode", "executor.output")
+# counter: worker launches that read the packed operand through a table
+TABLE_LAUNCHES = "executor.table_launches"
 _STAGE = {"cat": "executor", "track": "executor"}
 
 
@@ -240,8 +251,16 @@ class CodedExecutor:
                                track="plan"):
                 self.packed = pack_coded_blocks(np.asarray(self.coded),
                                                 bk or tile, bm or tile)
-            self.cache = DecodeCache(np.asarray(self.G), k,
-                                     maxsize=cache_size)
+            kernel = self.backend in _KERNEL_BACKENDS
+            # the kernel path reads the fastest-k workers' tiles through
+            # a block-row table, built once a pattern with its plan
+            self.cache = DecodeCache(
+                np.asarray(self.G), k, maxsize=cache_size,
+                tables=self.packed.block_rows if kernel else None)
+            # matmat: one table a worker, each with its own B shard
+            self._worker_tables = ([self.packed.block_rows([i])
+                                    for i in range(self.n)] if kernel
+                                   else None)
 
     def _bsr_shards(self):
         if self._bsr is None:
@@ -318,23 +337,20 @@ class CodedExecutor:
 
     def _matvec_kernel(self, xb, done):
         tr = self._tracer
-        plan, a_data, a_idx = traced_call(tr, SELECT, self._select, done,
-                                          **_STAGE)
-        y = traced_call(tr, WORKER, self._matvec_worker, a_data, a_idx, xb,
+        plan = traced_call(tr, SELECT, self.cache.plan, done, **_STAGE)
+        if tr is not None:
+            tr.count(TABLE_LAUNCHES)
+        y = traced_call(tr, WORKER, self._matvec_worker, plan.tables, xb,
                         **_STAGE)
         u = traced_call(tr, DECODE, self._decode, plan, y, **_STAGE)
         return traced_call(tr, OUTPUT, self._matvec_output, u, xb.shape[0],
                            **_STAGE)
 
-    def _select(self, done):
-        """The mask's decode plan and the selected workers' tiles, still
-        fused along the output-block axis."""
-        plan = self.cache.plan(done)
-        return (plan,) + self.packed.select_workers(plan.rows)
-
-    def _matvec_worker(self, a_data, a_idx, xb):
+    def _matvec_worker(self, tables, xb):
+        """One launch over the fastest-k workers' tiles, read in place."""
         b_op = _pad_to(xb.T, 0, self.packed.t_pad)
-        return worker_kernel(a_data, a_idx, b_op, interpret=self._interpret())
+        return worker_kernel(self.packed.a_data, tables, b_op,
+                             interpret=self._interpret())
 
     def _decode(self, plan, y):
         return decode_kernel(plan.hinv_dev, y.reshape(self.k, -1),
@@ -360,6 +376,7 @@ class CodedExecutor:
             for i in plan.rows:
                 with tr.span(SELECT, **_STAGE):
                     ops = self._worker_operands(int(i), coded_b)
+                tr.count(TABLE_LAUNCHES)
                 with tr.span(WORKER, **_STAGE):
                     prods.append(worker_kernel(*ops, interpret=interpret))
         u = traced_call(tr, DECODE, self._matmat_decode, plan, prods,
@@ -369,9 +386,10 @@ class CodedExecutor:
                            **_STAGE)
 
     def _worker_operands(self, i: int, coded_b):
-        """Worker ``i``'s tiles and its coded B shard, padded."""
-        a_data, a_idx = self.packed.worker_view(i)
-        return a_data, a_idx, _pad_to(coded_b[i], 0, self.packed.t_pad)
+        """The packed operand, worker ``i``'s table into it, and its
+        coded B shard, padded."""
+        return (self.packed.a_data, self._worker_tables[i],
+                _pad_to(coded_b[i], 0, self.packed.t_pad))
 
     def _matmat_decode(self, plan, prods):
         y = jnp.stack(prods)[:, : self.packed.c]        # (k, ca, cb)

@@ -14,8 +14,10 @@ one packed operand shared by every backend of the executor:
     nonzero-tile count over workers) and concatenated along the
     output-block axis, so a single kernel launch computes every
     worker's product ``coded_i^T @ B`` when B is shared (matvec);
-  * per-worker views are cheap slices for the matmat path where each
-    worker multiplies a different B shard;
+  * a block-row table (``block_rows``) names the workers a launch
+    computes -- the fastest k of a straggler pattern (matvec), or one
+    worker with its own B shard (matmat) -- and the kernel reads their
+    tiles in place;
   * ``tile_counts`` records the true nonzero-tile count per worker --
     the quantity that scales with omega (asserted in tests, reported
     by the benchmarks).
@@ -28,6 +30,7 @@ sees the packed arrays.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -65,20 +68,27 @@ class PackedShards:
         return int(self.a_idx.shape[1])
 
     def worker_view(self, i: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """(a_data, a_idx) slice for worker i (matmat path)."""
+        """(a_data, a_idx) slice for worker i."""
         lo, hi = i * self.mb, (i + 1) * self.mb
         return self.a_data[lo:hi], self.a_idx[lo:hi]
 
-    def select_workers(self, rows: np.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-        """Packed operand restricted to the given workers, still fused
-        along the output-block axis (fastest-k compute: stragglers'
-        tiles are never touched)."""
+    def block_rows(self, rows) -> "BlockRows":
+        """Block-row table of the given workers, in order: the worker
+        kernel reads their tiles where they lie (fastest-k compute:
+        stragglers' tiles are never touched, and none are copied)."""
         rows = np.asarray(rows)
-        d = self.a_data.reshape(self.n, self.mb, -1, self.bk, self.bm)
-        ix = self.a_idx.reshape(self.n, self.mb, -1)
-        sel_d = d[rows].reshape(len(rows) * self.mb, -1, self.bk, self.bm)
-        sel_i = ix[rows].reshape(len(rows) * self.mb, -1)
-        return sel_d, sel_i
+        blocks = (rows[:, None] * self.mb + np.arange(self.mb)).ravel()
+        blocks = jnp.asarray(blocks, jnp.int32)
+        return BlockRows(blocks, self.a_idx[blocks])
+
+
+class BlockRows(NamedTuple):
+    """Rows of a packed operand for the worker kernel: output
+    block-column m reads block-row ``blocks[m]``, whose K-block indices
+    are ``idx[m]``."""
+
+    blocks: jnp.ndarray    # (Mb,) int32
+    idx: jnp.ndarray       # (Mb, J) int32
 
 
 def pack_coded_blocks(coded, bk: int = 8, bm: int = 8) -> PackedShards:

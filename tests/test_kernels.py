@@ -23,6 +23,7 @@ from repro.kernels.ref import (
     decode_matmul_ref,
     pack_bcsr,
 )
+from repro.runtime import pack_coded_blocks
 
 TOL = dict(rtol=2e-5, atol=2e-5)
 TOL_BF16 = dict(rtol=2e-2, atol=2e-2)
@@ -68,6 +69,42 @@ class TestBcsrMatmul:
         ref = bcsr_matmul_ref(jnp.asarray(a, jnp.float32),
                               jnp.asarray(b, jnp.float32))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **tol)
+
+    @pytest.mark.parametrize("rows", [None, [4, 1, 3], [2], [3, 0, 3]],
+                             ids=["identity", "unsorted", "one", "repeated"])
+    def test_block_table_matches_gathered_operand(self, rows):
+        """Rows read through a table equal the kernel on the explicitly
+        gathered rows, bit for bit: the same tiles in the same order."""
+        rng = np.random.default_rng(6)
+        workers, mb = 5, 2
+        packed = pack_coded_blocks(np.stack([
+            make_block_sparse(rng, 64, mb * 8, 8, 8, 0.5)
+            for _ in range(workers)]), 8, 8)
+        a_data, a_idx = np.asarray(packed.a_data), np.asarray(packed.a_idx)
+        b = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
+        if rows is None:
+            blocks = np.arange(workers * mb, dtype=np.int32)
+            out = bcsr_matmul(jnp.asarray(a_data), jnp.asarray(a_idx), b,
+                              bn=16, interpret=True)
+        else:
+            blocks = (np.asarray(rows)[:, None] * mb
+                      + np.arange(mb)).ravel().astype(np.int32)
+            out = bcsr_matmul(jnp.asarray(a_data), jnp.asarray(a_idx[blocks]),
+                              b, blocks=jnp.asarray(blocks), bn=16,
+                              interpret=True)
+        gathered = bcsr_matmul(jnp.asarray(a_data[blocks]),
+                               jnp.asarray(a_idx[blocks]), b, bn=16,
+                               interpret=True)
+        assert out.shape == (len(blocks) * 8, 16)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(gathered))
+
+    def test_block_table_must_fit_the_operand(self):
+        a_data = jnp.zeros((4, 3, 8, 8), jnp.float32)
+        b = jnp.zeros((32, 8), jnp.float32)
+        with pytest.raises(ValueError, match="do not fit"):
+            bcsr_matmul(a_data, jnp.zeros((2, 3), jnp.int32), b,
+                        blocks=jnp.zeros((3,), jnp.int32), bn=8,
+                        interpret=True)
 
     def test_packed_ref_matches_dense_ref(self):
         rng = np.random.default_rng(3)

@@ -74,19 +74,79 @@ class TestPacking:
         assert sum(ps.tile_counts) == sum(pd.tile_counts) // 4
         assert ps.slots < pd.slots
 
-    def test_select_workers_matches_views(self):
+    @pytest.mark.parametrize("stragglers", [[], [0, 3], [5, 1]])
+    def test_executor_tables_pick_worker_views(self, stragglers):
+        """The block-row tables the kernel reads through name the same
+        tiles and K-indices as each selected worker's view."""
         rng = np.random.default_rng(1)
-        coded = rng.standard_normal((6, 16, 8)).astype(np.float32)
-        packed = pack_coded_blocks(coded, 8, 8)
-        rows = np.array([4, 1, 3])
-        sel_d, sel_i = packed.select_workers(rows)
-        for j, i in enumerate(rows):
-            vd, vi = packed.worker_view(int(i))
-            lo, hi = j * packed.mb, (j + 1) * packed.mb
-            np.testing.assert_array_equal(np.asarray(sel_d[lo:hi]),
-                                          np.asarray(vd))
-            np.testing.assert_array_equal(np.asarray(sel_i[lo:hi]),
-                                          np.asarray(vi))
+        sch, A, coded, G = build_coded(rng, 6, 4, 16, 40)
+        ex = CodedExecutor(coded, G, 4, 40, backend="pallas-interpret")
+        packed = ex.packed
+        done = np.ones(6, bool)
+        done[stragglers] = False
+        plan = ex.cache.plan(done)
+        a_data = np.asarray(packed.a_data)
+        for j, i in enumerate(plan.rows):
+            vd, vi = (np.asarray(v) for v in packed.worker_view(int(i)))
+            for tables, at in ((plan.tables, j), (ex._worker_tables[i], 0)):
+                blocks = np.asarray(tables.blocks)
+                idx = np.asarray(tables.idx)
+                lo, hi = at * packed.mb, (at + 1) * packed.mb
+                np.testing.assert_array_equal(a_data[blocks[lo:hi]], vd)
+                np.testing.assert_array_equal(idx[lo:hi], vi)
+
+
+class TestBlockRowTables:
+    """The kernel path reads the fastest-k workers' tiles in place
+    through a per-pattern table: a new mask is data, not a program."""
+
+    def test_new_mask_lowers_no_program(self):
+        from repro.obs import LOWERINGS, Tracer
+
+        rng = np.random.default_rng(14)
+        sch, A, coded, G = build_coded(rng, 6, 4, 32, 24)
+        ex = CodedExecutor(coded, G, 4, 24, backend="pallas-interpret")
+        ref = CodedExecutor(coded, G, 4, 24, backend="reference")
+        x = jnp.asarray(rng.standard_normal((3, 32)), jnp.float32)
+        masks = [np.ones(6, bool) for _ in range(3)]
+        for m, off in zip(masks, ([0, 1], [2, 5], [3, 4])):
+            m[off] = False
+        for m in masks[:2]:
+            ex.matvec(x, m).block_until_ready()
+        tr = Tracer(capacity=8)
+        before = tr.counters().get(LOWERINGS, 0)
+        out = ex.matvec(x, masks[2]).block_until_ready()
+        assert tr.counters().get(LOWERINGS, 0) == before
+        assert ex.cache.misses == 3
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(ref.matvec(x, masks[2])),
+                                   **TOL)
+
+    def test_table_launches_count_one_a_matvec_and_k_a_matmat(
+            self, monkeypatch):
+        import repro.obs.trace as trace_mod
+        from repro.api import compile_plan
+        from repro.obs import Tracer
+        from repro.runtime.executor import TABLE_LAUNCHES
+
+        tr = Tracer(capacity=4096)
+        monkeypatch.setenv("REPRO_TRACE", "1")
+        monkeypatch.setattr(trace_mod, "_GLOBAL", tr)
+        rng = np.random.default_rng(15)
+        A = jnp.asarray(rng.standard_normal((96, 64)), jnp.float32)
+        mv = compile_plan(A, scheme="proposed", n=6, s=2,
+                          backend="pallas-interpret")
+        mm = compile_plan(A, scheme="proposed", n=8, k_A=2, k_B=2,
+                          backend="pallas-interpret")
+        done = np.ones(8, bool)
+        done[[0, 5]] = False
+        x = jnp.asarray(rng.standard_normal((2, 96)), jnp.float32)
+        B = jnp.asarray(rng.standard_normal((96, 24)), jnp.float32)
+        for call, k in ((lambda: mv.matvec(x, done[:6]), 1),
+                        (lambda: mm.matmat(B, done), mm.k)):
+            before = tr.counters().get(TABLE_LAUNCHES, 0)
+            call().block_until_ready()
+            assert tr.counters()[TABLE_LAUNCHES] - before == k
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ from repro.runtime.executor import (
     encode_kernel,
     worker_kernel,
 )
-from repro.runtime.pack import _round_up
+from repro.runtime.pack import BlockRows, _round_up
 
 TILE = 128
 # one entry per system chip_smoke.py runs (c: block-column widths)
@@ -66,6 +66,12 @@ def _packed(spec, workers, t, c):
             spec((workers * mb, kb), jnp.int32))
 
 
+def _table(spec, workers, c, slots):
+    """Block-row table of ``workers`` workers' (c-wide) block-columns."""
+    rows = workers * (_round_up(c, TILE) // TILE)
+    return BlockRows(spec((rows,), jnp.int32), spec((rows, slots), jnp.int32))
+
+
 def _compile(fn, *args):
     compiled = fn.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -87,10 +93,12 @@ def test_encode_compiles(spec, cfg, k, c):
 
 @pytest.mark.parametrize("batch", [8, 136])
 def test_mv_worker_compiles(spec, batch):
-    # one fused launch over the fastest k workers' shards
-    a_data, a_idx = _packed(spec, MV["k"], MV["t"], MV["c"])
+    # one launch over the fastest k workers' tiles, read in place from
+    # the whole n-worker operand through a k-worker table
+    a_data, _ = _packed(spec, MV["n"], MV["t"], MV["c"])
+    tables = _table(spec, MV["k"], MV["c"], a_data.shape[1])
     b = spec((_round_up(MV["t"], TILE), batch))
-    _compile(worker_kernel, a_data, a_idx, b)
+    _compile(worker_kernel, a_data, tables, b)
 
 
 @pytest.mark.parametrize("batch", [8, 136])
@@ -101,10 +109,12 @@ def test_mv_decode_compiles(spec, batch):
 
 @pytest.mark.parametrize("cfg", [MM_FULL, MM_CHIP], ids=["full", "chip"])
 def test_mm_worker_compiles(spec, cfg):
-    # one launch per fastest-k worker, each on its own coded-B shard
-    a_data, a_idx = _packed(spec, 1, cfg["t"], cfg["ca"])
+    # one launch per fastest-k worker, each on its own coded-B shard,
+    # reading that worker's tiles from the whole operand through its table
+    a_data, _ = _packed(spec, cfg["n"], cfg["t"], cfg["ca"])
+    tables = _table(spec, 1, cfg["ca"], a_data.shape[1])
     b = spec((_round_up(cfg["t"], TILE), cfg["cb"]))
-    _compile(worker_kernel, a_data, a_idx, b)
+    _compile(worker_kernel, a_data, tables, b)
 
 
 @pytest.mark.parametrize("cfg", [MM_FULL, MM_CHIP], ids=["full", "chip"])
