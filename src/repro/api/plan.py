@@ -50,6 +50,7 @@ from ..runtime import (
     is_concrete as _is_concrete,
     support_tables,
 )
+from ..runtime.placement import PlacedExecutor, workers_per_device
 from .backends import choose_backend
 from .schemes import make_scheme
 
@@ -83,7 +84,8 @@ class CodedPlan:
     seed: int
     G: np.ndarray                   # (n_tasks, k) decode system matrix
     r: int | None = None            # logical output dim (None: aggregation-only)
-    executor: CodedExecutor | None = field(default=None, repr=False)
+    executor: CodedExecutor | PlacedExecutor | None = field(default=None,
+                                                            repr=False)
     # mm-only: per-call B-side encoding state
     cache_size: int = 64
     _rb: np.ndarray | None = field(default=None, repr=False)
@@ -94,6 +96,9 @@ class CodedPlan:
     # array reference, not a copy -- the caller's weights stay the
     # single allocation
     _A: object | None = field(default=None, repr=False)
+    # devices the workers are placed on (``repro.runtime.placement``);
+    # None: one device holds them all
+    devices: tuple | None = field(default=None, repr=False)
     _tracer: object | None = field(default=None, init=False, repr=False)
 
     def __post_init__(self):
@@ -131,6 +136,8 @@ class CodedPlan:
         if self.executor is not None and self.executor.cache is not None:
             d["decode_cache"] = {"hits": self.executor.cache.hits,
                                  "misses": self.executor.cache.misses}
+        if self.devices is not None and self.executor is not None:
+            d["placement"] = self.executor.placement()
         return d
 
     def worker_tile_counts(self) -> np.ndarray:
@@ -167,7 +174,11 @@ class CodedPlan:
     # -- per-call operations ----------------------------------------------
 
     def matvec(self, x, done=None):
-        """A^T x for x (t,) or (batch, t); tolerates any s stragglers."""
+        """A^T x for x (t,) or (batch, t); tolerates any s stragglers.
+
+        On a plan placed over devices, ``done=None`` decodes from the
+        workers that finished first, and the result lies on the device
+        that decoded it."""
         if self.kind != "mv":
             raise ValueError("matvec needs an mv plan; this plan is "
                              f"kind={self.kind!r}")
@@ -318,7 +329,7 @@ class CodedPlan:
 def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
                  k_A=None, k_B=None, capacities=None, seed: int = 0,
                  backend: str | None = "auto",
-                 cache_size: int = 64) -> CodedPlan:
+                 cache_size: int = 64, devices=None) -> CodedPlan:
     """Compile a ``CodedPlan`` (see module docstring).
 
     ``scheme`` is a registry name (``repro.api.list_schemes()``) or an
@@ -327,6 +338,10 @@ def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
     packed/reference crossover (``pallas`` on TPU); the
     ``REPRO_CODED_BACKEND`` env var overrides everything, including
     auto.  Without ``A`` the plan is aggregation-only.
+
+    ``devices`` places an MV plan's workers over those devices, ``n``
+    divided evenly among them (``repro.runtime.placement``); None keeps
+    them all on one device.
     """
     tr = default_tracer()
     t0 = time.perf_counter() if tr is not None else 0.0
@@ -336,11 +351,20 @@ def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
         sch = make_scheme(scheme, n=n, s=s, k_A=k_A, k_B=k_B,
                           capacities=capacities)
     kind = "mm" if isinstance(sch, MMScheme) else "mv"
+    if devices is not None:
+        if kind == "mm":
+            raise NotImplementedError(
+                "placing an MM plan's workers on devices is not supported; "
+                "compile it without devices=")
+        if A is None:
+            raise ValueError("a plan placed on devices needs its operand A")
+        devices = tuple(devices)
+        workers_per_device(sch.n, len(devices))
     G = np.asarray(system_matrix(sch, seed))
     resolved = choose_backend(A, backend)
 
     plan = CodedPlan(scheme=sch, kind=kind, backend=resolved, seed=seed,
-                     G=G, cache_size=cache_size)
+                     G=G, cache_size=cache_size, devices=devices)
 
     if A is not None:
         _attach_operand(plan, A, resolved)
@@ -378,9 +402,16 @@ def _attach_operand(plan: CodedPlan, A, resolved: str) -> None:
             plan._sup_b, plan._coef_b = (
                 (None, None) if resolved == "reference"
                 else support_tables(sch.supports_B, rb))
-    plan.executor = CodedExecutor(
-        _match_dtype(coded, A), jnp.asarray(plan.G, jnp.float32), k,
-        A.shape[1], backend=resolved, cache_size=plan.cache_size)
+    if plan.devices is None:
+        plan.executor = CodedExecutor(
+            _match_dtype(coded, A), jnp.asarray(plan.G, jnp.float32), k,
+            A.shape[1], backend=resolved, cache_size=plan.cache_size)
+    else:
+        with optional_span(tr, "plan.pack", cat="plan", track="plan"):
+            plan.executor = PlacedExecutor(
+                _match_dtype(coded, A), plan.G, k, A.shape[1],
+                plan.devices, resolved, cache_size=plan.cache_size)
+        del coded       # packed a device at a time; the shards go
     plan.r = A.shape[1]
     if _is_concrete(A):
         plan._A = A

@@ -73,6 +73,10 @@ PROGRAM_SPANS = (
     "executor.worker",    # worker-kernel launch (matvec: x padded first)
     "executor.decode",    # decode-kernel launch and its operand
     "executor.output",    # reshapes and slices to the output's layout
+    # a plan placed on devices (``repro.runtime.placement``)
+    "executor.launch",    # one device: x padded there, its kernel launch
+    "executor.harvest",   # last launch until k workers' results are ready
+    "executor.gather",    # those results moved to the decoding device
 )
 LOWERINGS = "jit.lowerings"
 # JAX's event around each lowering of a program to an MLIR module

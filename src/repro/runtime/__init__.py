@@ -16,6 +16,9 @@ scaling end-to-end:
     ``pallas`` / ``pallas-interpret`` backends; every coded call site
     (``CodedOperator``, ``CodedLinear``, ``coded_matvec``/``matmat``,
     the serving engine) routes through it.
+  * ``placement``    -- ``PlacedExecutor``: an MV plan's workers placed
+    over several devices, one launch a device, decoded from the first
+    devices to finish (``compile_plan(..., devices=)``).
 
 Force a backend with the ``REPRO_CODED_BACKEND`` environment variable
 (e.g. ``REPRO_CODED_BACKEND=packed`` on CPU, ``pallas-interpret`` to
@@ -34,3 +37,4 @@ from .executor import (  # noqa: F401
     support_tables,
 )
 from .pack import PackedShards, pack_coded_blocks, unpack_coded_blocks  # noqa: F401
+from .placement import PlacedExecutor  # noqa: F401

@@ -22,9 +22,10 @@ from __future__ import annotations
 import threading
 from collections import OrderedDict
 from collections.abc import Callable
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -39,6 +40,16 @@ class DecodePlan:
     hinv_dev: jnp.ndarray      # same, device-resident for the kernels
     tables: Any = None         # the owner's per-pattern operands (the
                                # executor's block-row table), if any
+    # hinv on each device that decodes this pattern (placed plans)
+    copies: dict = field(default_factory=dict, compare=False, repr=False)
+
+    def hinv_on(self, device) -> jnp.ndarray:
+        """``hinv`` on ``device``: copied there on first use, kept and
+        evicted with the plan."""
+        copy = self.copies.get(device)
+        if copy is None:
+            copy = self.copies[device] = jax.device_put(self.hinv, device)
+        return copy
 
 
 class DecodeCache:

@@ -162,6 +162,14 @@ def decode_kernel(hinv, y, *, interpret: bool = False):
     return out[:, :p]
 
 
+def matvec_output(u, k: int, c: int, c_pad: int, r: int, b: int):
+    """Decoded unknowns (k, c_pad * b) -> A^T x (b, r), eagerly."""
+    u = u.reshape(k, c_pad, b)
+    u = u[:, :c]                                    # drop padding
+    out = jnp.moveaxis(u, 2, 0).reshape(b, -1)      # (b, k*c)
+    return out[:, :r]
+
+
 # ---------------------------------------------------------------------------
 # Encoding (Alg. 1 / Alg. 2 line: coded_i = sum_j coef[i,j] * blocks[sup[i,j]])
 # ---------------------------------------------------------------------------
@@ -358,10 +366,7 @@ class CodedExecutor:
 
     def _matvec_output(self, u, b):
         packed = self.packed
-        u = u.reshape(self.k, packed.c_pad, b)
-        u = u[:, : packed.c]                            # drop padding
-        out = jnp.moveaxis(u, 2, 0).reshape(b, -1)      # (b, k*c)
-        return out[:, : self.r]
+        return matvec_output(u, self.k, packed.c, packed.c_pad, self.r, b)
 
     def _matmat_kernel(self, coded_b, done):
         tr = self._tracer

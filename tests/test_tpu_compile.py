@@ -122,3 +122,23 @@ def test_mm_decode_compiles(spec, cfg):
     k = cfg["k_a"] * cfg["k_b"]
     p = cfg["ca"] * cfg["cb"]          # 5,000,000 at full size
     _compile(decode_kernel, spec((k, k)), spec((k, p)))
+
+
+@pytest.mark.parametrize("chip", range(4))
+def test_mv_placed_compiles_on_each_chip(topo, chip):
+    # a plan placed over the 2x2 host (repro.runtime.placement): chip c
+    # launches over its own n/4 workers' tiles, and decodes the pattern
+    # when it is the first to finish
+    on = SingleDeviceSharding(topo.devices[chip])
+
+    def spec_on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=on)
+
+    per = MV["n"] // 4
+    a_data, _ = _packed(spec_on, per, MV["t"], MV["c"])
+    tables = _table(spec_on, per, MV["c"], a_data.shape[1])
+    _compile(worker_kernel, a_data, tables,
+             spec_on((_round_up(MV["t"], TILE), 8)))
+    p = _round_up(MV["c"], TILE) * 8
+    _compile(decode_kernel, spec_on((MV["k"], MV["k"])),
+             spec_on((MV["k"], p)))
