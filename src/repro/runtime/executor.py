@@ -32,6 +32,7 @@ to the reference path, so a single call site serves both worlds.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import threading
 from collections import Counter
@@ -40,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.bcsr_matmul import bcsr_matmul
+from ..kernels.bcsr_matmul import bcsr_grid, bcsr_matmul
 from ..kernels.cyclic_encode import cyclic_encode
 from ..kernels.decode_matmul import decode_matmul
 from ..kernels.ref import cyclic_encode_ref
@@ -152,6 +153,12 @@ def worker_kernel(a_data, a_idx, b, *, interpret: bool = False):
     return out[:, :n]
 
 
+def worker_grid(a_data, table: BlockRows, n: int) -> tuple[int, int, int]:
+    """The grid ``worker_kernel`` launches over for b of ``n`` columns."""
+    bn, n_pad = lane_tile(n, 128)
+    return bcsr_grid(a_data.shape, table.blocks.shape[0], n_pad, bn)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_kernel(hinv, y, *, interpret: bool = False):
     """``decode_matmul`` U = hinv @ y for y (k, P), P padded to its tile."""
@@ -217,8 +224,10 @@ def encode_blocks(blocks, sup, coef, backend: str | None = None) -> jnp.ndarray:
 # the per-call stages of the kernel path, each a span where tracing is on
 SELECT, WORKER, DECODE, OUTPUT = ("executor.select", "executor.worker",
                                   "executor.decode", "executor.output")
-# counter: worker launches that read the packed operand through a table
+# counters: worker launches that read the packed operand through a
+# table, and the grid steps of those launches
 TABLE_LAUNCHES = "executor.table_launches"
+GRID_STEPS = "kernel.bcsr_grid_steps"
 _STAGE = {"cat": "executor", "track": "executor"}
 
 
@@ -348,6 +357,8 @@ class CodedExecutor:
         plan = traced_call(tr, SELECT, self.cache.plan, done, **_STAGE)
         if tr is not None:
             tr.count(TABLE_LAUNCHES)
+            tr.count(GRID_STEPS, math.prod(worker_grid(
+                self.packed.a_data, plan.tables, xb.shape[0])))
         y = traced_call(tr, WORKER, self._matvec_worker, plan.tables, xb,
                         **_STAGE)
         u = traced_call(tr, DECODE, self._decode, plan, y, **_STAGE)
@@ -382,6 +393,8 @@ class CodedExecutor:
                 with tr.span(SELECT, **_STAGE):
                     ops = self._worker_operands(int(i), coded_b)
                 tr.count(TABLE_LAUNCHES)
+                tr.count(GRID_STEPS, math.prod(worker_grid(
+                    ops[0], ops[1], coded_b.shape[2])))
                 with tr.span(WORKER, **_STAGE):
                     prods.append(worker_kernel(*ops, interpret=interpret))
         u = traced_call(tr, DECODE, self._matmat_decode, plan, prods,

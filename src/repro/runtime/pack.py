@@ -11,7 +11,8 @@ This module converts a stack of coded shards ``coded (n, t, c)`` into
 one packed operand shared by every backend of the executor:
 
   * all workers are packed to a **common slot count J** (the max
-    nonzero-tile count over workers) and concatenated along the
+    nonzero-tile count over workers, rounded up to the worker kernel's
+    slot groups by ``padded_slots``) and concatenated along the
     output-block axis, so a single kernel launch computes every
     worker's product ``coded_i^T @ B`` when B is shared (matvec);
   * a block-row table (``block_rows``) names the workers a launch
@@ -35,9 +36,7 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
-
-def _round_up(x: int, m: int) -> int:
-    return x + (-x) % m
+from ..kernels.bcsr_matmul import _round_up, padded_slots
 
 
 @dataclass(frozen=True)
@@ -114,7 +113,7 @@ def pack_coded_blocks(coded, bk: int = 8, bm: int = 8) -> PackedShards:
     nz = np.abs(blocks).max(axis=(3, 4)) > 0           # (n, mb, kb)
     tile_counts = tuple(int(x) for x in nz.sum(axis=(1, 2)))
     slot_counts = tuple(tuple(int(x) for x in row) for row in nz.sum(axis=2))
-    j = max(int(nz.sum(axis=2).max()), 1)
+    j = padded_slots(max(int(nz.sum(axis=2).max()), 1))
 
     a_data = np.zeros((n, mb, j, bk, bm), dtype=a.dtype)
     a_idx = np.zeros((n, mb, j), dtype=np.int32)

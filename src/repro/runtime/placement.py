@@ -43,9 +43,9 @@ import numpy as np
 
 from ..obs.trace import default_tracer, traced_call
 from .decode_cache import DecodeCache
-from .executor import (_KERNEL_BACKENDS, DECODE, OUTPUT, SELECT,
-                       _pad_to, decode_kernel, is_concrete, matvec_output,
-                       worker_kernel)
+from .executor import (_KERNEL_BACKENDS, DECODE, GRID_STEPS, OUTPUT,
+                       SELECT, _pad_to, decode_kernel, is_concrete,
+                       matvec_output, worker_grid, worker_kernel)
 from .pack import pack_coded_blocks
 
 # the per-call stages of a placed product, each a span where tracing is on
@@ -169,6 +169,10 @@ class _Group:
                                      interpret=interpret)
         return self.pending
 
+    def grid_steps(self, batch: int) -> int:
+        """Grid steps of one launch over x of ``batch`` rows."""
+        return math.prod(worker_grid(self.packed.a_data, self.table, batch))
+
 
 class PlacedExecutor:
     """An MV plan's coded workers placed over ``devices`` (see the
@@ -282,6 +286,8 @@ class PlacedExecutor:
             tr.count(CHIP_LAUNCHES, len(outs))
             tr.count(CHIPS_SKIPPED_BUSY, len(skipped))
             tr.count(HARVEST_POLLS, sweeps)
+            tr.count(GRID_STEPS, sum(self.groups[c].grid_steps(xb.shape[0])
+                                     for c in outs))
         out = self._finish(dplan, outs, order[0], xb.shape[0])
         self.records.append(CallRecord(
             tuple(outs), tuple(skipped), tuple(int(i) for i in dplan.rows),

@@ -5,6 +5,8 @@ plus hypothesis property tests tying the kernels back to the coded-
 computation semantics (encode kernel == encoding matrix product).
 """
 
+import importlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +14,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import mv_encoding_matrix, proposed_mv
-from repro.kernels.bcsr_matmul import bcsr_matmul
+from repro.kernels.bcsr_matmul import bcsr_grid, bcsr_matmul, padded_slots
 from repro.kernels.cyclic_encode import cyclic_encode
 from repro.kernels.decode_matmul import decode_matmul
 from repro.kernels.ops import coded_worker_matmul, decode_unknowns, encode_submatrices
@@ -25,6 +27,8 @@ from repro.kernels.ref import (
 )
 from repro.runtime import pack_coded_blocks
 
+# the module, which the package's ``bcsr_matmul`` function shadows
+bcsr_module = importlib.import_module("repro.kernels.bcsr_matmul")
 TOL = dict(rtol=2e-5, atol=2e-5)
 TOL_BF16 = dict(rtol=2e-2, atol=2e-2)
 
@@ -97,6 +101,58 @@ class TestBcsrMatmul:
                                interpret=True)
         assert out.shape == (len(blocks) * 8, 16)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(gathered))
+
+    @pytest.mark.parametrize("tiles,rounded,group", [
+        (64, True, 32),     # J a multiple of the slot group
+        (37, True, 19),     # the packer rounds 37 slots to 2 groups of 19
+        (5, True, 5),       # J below SLOT_GROUP: one group of J slots
+        (1, True, 1),       # one slot a step
+        (37, False, 1),     # an unrounded prime J: one slot a step
+    ], ids=["multiple", "rounded", "below", "one", "unrounded"])
+    @pytest.mark.parametrize("rows", [None, [3, 0, 3, 1]],
+                             ids=["identity", "repeated-unsorted"])
+    def test_slot_groups_match_one_slot_a_step(self, monkeypatch, tiles,
+                                               rounded, group, rows):
+        """A walk of several K-slots a grid step gives the walk of one
+        slot a step bit for bit: the same tiles added in the same order,
+        pad slots (zero tiles at K-block 0) included."""
+        rng = np.random.default_rng(tiles)
+        workers, mb, kb = 4, 2, 64
+        coded = rng.standard_normal((workers, kb * 8, mb * 8))
+        for i in range(workers):
+            for m in range(mb):       # `tiles` K-tiles kept, K-block 0
+                order = rng.permutation(kb)     # among them in every other
+                if (i + m) % 2:
+                    order = np.r_[0, order[order != 0]]
+                coded[i].reshape(kb, 8, mb, 8)[order[tiles:], :, m] = 0
+        packed = pack_coded_blocks(coded.astype(np.float32), 8, 8)
+        a_data, a_idx = np.asarray(packed.a_data), np.asarray(packed.a_idx)
+        assert packed.slot_counts == ((tiles,) * mb,) * workers
+        assert packed.slots == padded_slots(tiles)
+        if not rounded:
+            a_data, a_idx = a_data[:, :tiles], a_idx[:, :tiles]
+        np.testing.assert_array_equal(a_data[:, tiles:], 0)
+        np.testing.assert_array_equal(a_idx[:, tiles:], 0)
+        blocks = np.arange(workers * mb) if rows is None else \
+            (np.asarray(rows)[:, None] * mb + np.arange(mb)).ravel()
+        blocks = blocks.astype(np.int32)
+        b = jnp.asarray(rng.standard_normal((kb * 8, 16)), jnp.float32)
+        j = a_data.shape[1]
+        assert bcsr_grid(a_data.shape, len(blocks), 16) == \
+            (len(blocks), 1, j // group)
+
+        def run():
+            return np.asarray(bcsr_matmul(
+                jnp.asarray(a_data), jnp.asarray(a_idx[blocks]), b,
+                blocks=jnp.asarray(blocks), bn=16, interpret=True))
+
+        out = run()
+        monkeypatch.setattr(bcsr_module, "SLOT_GROUP", 1)
+        assert bcsr_grid(a_data.shape, len(blocks), 16)[2] == j
+        np.testing.assert_array_equal(out, run())
+        np.testing.assert_allclose(out, np.asarray(bcsr_matmul_packed_ref(
+            jnp.asarray(a_data[blocks]), jnp.asarray(a_idx[blocks]), b)),
+            **TOL)
 
     def test_block_table_must_fit_the_operand(self):
         a_data = jnp.zeros((4, 3, 8, 8), jnp.float32)

@@ -123,6 +123,7 @@ PROGRAM = """
     import json, os
     import jax, jax.numpy as jnp, numpy as np
     from repro.api import compile_plan
+    from repro.kernels.bcsr_matmul import bcsr_grid
     from repro.obs import default_tracer
     from repro.runtime.placement import RECORDS, device_mask
 
@@ -143,7 +144,13 @@ PROGRAM = """
         jax.sharding.PartitionSpec()))
     G = np.asarray(plan.G, np.float64)
     out = {"describe": plan.describe(), "calls": [],
-           "records_bound": ex.records.maxlen == RECORDS}
+           "records_bound": ex.records.maxlen == RECORDS,
+           "grid_steps": [int(np.prod(bcsr_grid(
+               g.packed.a_data.shape, 3 * g.packed.mb, 8)))
+               for g in ex.groups],
+           "base_grid_steps": int(np.prod(bcsr_grid(
+               base.executor.packed.a_data.shape,
+               9 * base.executor.packed.mb, 8)))}
 
     def report(name, y, done, xs=x):
         rec = ex.records[-1]
@@ -286,6 +293,11 @@ def test_record_spans_and_counters_fill_in(placed):
         sum(len(c["launched"]) for c in calls)
     assert counters["executor.chips_skipped_busy"] == 1
     assert counters["executor.harvest_polls"] >= len(calls)
+    # each launch adds its grid (x of 8 or 1 rows: one N tile either
+    # way), and so does each call's unplaced product it is compared with
+    assert counters["kernel.bcsr_grid_steps"] == sum(
+        placed["grid_steps"][c] for call in calls
+        for c in call["launched"]) + len(calls) * placed["base_grid_steps"]
     assert {"plan.matvec", "executor.launch", "executor.harvest",
             "executor.gather", "executor.decode",
             "executor.output"} <= set(placed["spans"])

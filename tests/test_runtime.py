@@ -2,6 +2,8 @@
 backend parity (reference vs packed vs pallas-interpret), and the
 omega/k_A work-scaling structure that is the paper's whole point."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,7 +21,10 @@ from repro.core import (
 )
 from repro.core.coded_matmul import split_block_columns
 from repro.core.weights import mv_weight
+from repro.kernels.bcsr_matmul import (SLOT_GROUP, bcsr_grid, padded_slots,
+                                       slot_group)
 from repro.parallel.coded_layer import CodedLinear
+from repro.runtime.pack import bsr_shards
 from repro.runtime import (
     BACKENDS,
     CodedExecutor,
@@ -73,6 +78,34 @@ class TestPacking:
         ps = pack_coded_blocks(sparse, 8, 8)
         assert sum(ps.tile_counts) == sum(pd.tile_counts) // 4
         assert ps.slots < pd.slots
+
+    @pytest.mark.parametrize("most", [1, 5, 32, 33, 37, 64, 157])
+    def test_pack_rounds_slots_to_the_kernel_groups(self, most):
+        """J is rounded up to the worker kernel's slot groups with zero
+        pad slots at K-block 0; the counts, the round trip and the BSR
+        export see only the real tiles."""
+        rng = np.random.default_rng(most)
+        kb, workers = most + 3, 3
+        coded = rng.standard_normal((workers, kb * 8, 16)).astype(np.float32)
+        kept = [most, max(most - 2, 1), 1]      # K-tiles of each worker
+        for i, keep in enumerate(kept):
+            coded[i, keep * 8:] = 0
+        packed = pack_coded_blocks(coded, 8, 8)
+        j = packed.slots
+        assert j == padded_slots(most) and j - most < -(-most // SLOT_GROUP)
+        assert slot_group(j, 8, 8, 8) == j // -(-most // SLOT_GROUP)
+        assert packed.tile_counts == tuple(2 * k for k in kept)
+        assert packed.slot_counts == tuple((k, k) for k in kept)
+        a_data = np.asarray(packed.a_data).reshape(workers, 2, j, 8, 8)
+        a_idx = np.asarray(packed.a_idx).reshape(workers, 2, j)
+        for i, keep in enumerate(kept):
+            np.testing.assert_array_equal(a_data[i, :, keep:], 0)
+            np.testing.assert_array_equal(a_idx[i, :, keep:], 0)
+        np.testing.assert_array_equal(unpack_coded_blocks(packed), coded)
+        x = rng.standard_normal(kb * 8).astype(np.float32)
+        for i, shard in enumerate(bsr_shards(packed)):
+            assert shard.nnz == packed.tile_counts[i] * 64
+            np.testing.assert_allclose(shard @ x, coded[i].T @ x, **TOL)
 
     @pytest.mark.parametrize("stragglers", [[], [0, 3], [5, 1]])
     def test_executor_tables_pick_worker_views(self, stragglers):
@@ -147,6 +180,41 @@ class TestBlockRowTables:
             before = tr.counters().get(TABLE_LAUNCHES, 0)
             call().block_until_ready()
             assert tr.counters()[TABLE_LAUNCHES] - before == k
+
+    def test_grid_steps_count_each_launchs_grid(self, monkeypatch):
+        """A traced product adds the grid of each worker launch: one
+        over the fastest k workers' rows (MV), one a worker (MM)."""
+        import repro.obs.trace as trace_mod
+        from repro.api import compile_plan
+        from repro.obs import Tracer
+        from repro.runtime.executor import GRID_STEPS
+
+        tr = Tracer(capacity=4096)
+        monkeypatch.setenv("REPRO_TRACE", "1")
+        monkeypatch.setattr(trace_mod, "_GLOBAL", tr)
+        rng = np.random.default_rng(16)
+        A = jnp.asarray(rng.standard_normal((96, 64)), jnp.float32)
+        mv = compile_plan(A, scheme="proposed", n=6, s=2,
+                          backend="pallas-interpret")
+        mm = compile_plan(A, scheme="proposed", n=8, k_A=2, k_B=2,
+                          backend="pallas-interpret")
+        x = jnp.asarray(rng.standard_normal((2, 96)), jnp.float32)
+        B = jnp.asarray(rng.standard_normal((96, 24)), jnp.float32)
+        mv_packed, mm_packed = mv.executor.packed, mm.executor.packed
+        assert mv_packed.slots == 12 and mm_packed.slots == 12
+        # MV: k=4 workers' 2 block-columns, x's 2 rows, 12 slots in
+        # one group; MM: each of k=4 launches one worker's 4 block-
+        # columns against its 12-wide B shard
+        mv_steps = math.prod(bcsr_grid(mv_packed.a_data.shape,
+                                       4 * mv_packed.mb, 2))
+        mm_steps = math.prod(bcsr_grid(mm_packed.a_data.shape,
+                                       mm_packed.mb, 12))
+        assert (mv_steps, mm_steps) == (4 * 2, 4)
+        for call, steps in ((lambda: mv.matvec(x), mv_steps),
+                            (lambda: mm.matmat(B), mm.k * mm_steps)):
+            before = tr.counters().get(GRID_STEPS, 0)
+            call().block_until_ready()
+            assert tr.counters()[GRID_STEPS] - before == steps
 
 
 # ---------------------------------------------------------------------------

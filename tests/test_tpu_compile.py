@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.bcsr_matmul import bcsr_grid, padded_slots
 from repro.runtime.executor import (
     decode_kernel,
     encode_kernel,
@@ -59,11 +60,12 @@ def spec(topo):
 
 def _packed(spec, workers, t, c):
     """Packed operand of ``workers`` coded shards (t, c) at 128 tiles,
-    every K-tile kept (the densest packing the operand can give)."""
-    kb = _round_up(t, TILE) // TILE
+    every K-tile kept (the densest packing the operand can give), its
+    slots rounded up to the kernel's slot groups as the packer does."""
+    slots = padded_slots(_round_up(t, TILE) // TILE)
     mb = _round_up(c, TILE) // TILE
-    return (spec((workers * mb, kb, TILE, TILE)),
-            spec((workers * mb, kb), jnp.int32))
+    return (spec((workers * mb, slots, TILE, TILE)),
+            spec((workers * mb, slots), jnp.int32))
 
 
 def _table(spec, workers, c, slots):
@@ -142,3 +144,22 @@ def test_mv_placed_compiles_on_each_chip(topo, chip):
     p = _round_up(MV["c"], TILE) * 8
     _compile(decode_kernel, spec_on((MV["k"], MV["k"])),
              spec_on((MV["k"], p)))
+
+
+@pytest.mark.parametrize("t,workers,rows,c,n,bn,grid", [
+    # one MV launch: 9 of 12 workers' 14 block-columns, x of 8 rows
+    (MV["t"], MV["n"], MV["k"], MV["c"], 8, 8, (126, 1, 5)),
+    # one MM launch: one worker's 14 block-columns, B 1400 wide padded
+    # to 11 tiles of 128
+    (MM_CHIP["t"], MM_CHIP["n"], 1, MM_CHIP["ca"], 1408, 128, (14, 11, 4)),
+    # one chip's launch of a placed MV plan: its own 3 workers
+    (MV["t"], MV["n"] // 4, MV["n"] // 4, MV["c"], 8, 8, (42, 1, 5)),
+], ids=["mv", "mm-chip", "mv-placed"])
+def test_worker_grid_at_the_papers_shapes(t, workers, rows, c, n, bn, grid):
+    """The 157 (MV) and 110 (MM) K-tiles of a block-column are packed
+    to 160 and 112 slots and walked 32 and 28 a grid step: 5 and 4
+    steps a block-column (one slot a step: 157 and 110)."""
+    mb = _round_up(c, TILE) // TILE
+    slots = padded_slots(_round_up(t, TILE) // TILE)
+    assert bcsr_grid((workers * mb, slots, TILE, TILE), rows * mb, n,
+                     bn) == grid
