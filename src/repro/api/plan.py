@@ -50,7 +50,6 @@ from ..runtime import (
     is_concrete as _is_concrete,
     support_tables,
 )
-from ..runtime.placement import PlacedExecutor, workers_per_device
 from .backends import choose_backend
 from .schemes import make_scheme
 
@@ -84,8 +83,7 @@ class CodedPlan:
     seed: int
     G: np.ndarray                   # (n_tasks, k) decode system matrix
     r: int | None = None            # logical output dim (None: aggregation-only)
-    executor: CodedExecutor | PlacedExecutor | None = field(default=None,
-                                                            repr=False)
+    executor: CodedExecutor | None = field(default=None, repr=False)
     # mm-only: per-call B-side encoding state
     cache_size: int = 64
     _rb: np.ndarray | None = field(default=None, repr=False)
@@ -96,7 +94,7 @@ class CodedPlan:
     # array reference, not a copy -- the caller's weights stay the
     # single allocation
     _A: object | None = field(default=None, repr=False)
-    # devices the workers are placed on (``repro.runtime.placement``);
+    # devices the workers are placed on (``repro.runtime.executor``);
     # None: one device holds them all
     devices: tuple | None = field(default=None, repr=False)
     _tracer: object | None = field(default=None, init=False, repr=False)
@@ -238,8 +236,6 @@ class CodedPlan:
                              f"kind={self.kind!r}")
         k = self.k
         task_done = self._task_done(done)
-        if task_done is None:
-            task_done = np.ones(self.n_tasks, bool)
         if _is_concrete(task_done):
             dplan = self._decode_cache().plan(task_done)
             # a^T G[rows] = 1^T  <=>  a = (G[rows]^{-1})^T 1 = colsums(hinv)
@@ -319,10 +315,8 @@ class CodedPlan:
             # consult a cache -- warming one would be a wasted inversion
             return self
         task_done = self._task_done(done)
-        if task_done is None:
-            task_done = np.ones(self.n_tasks, bool)
         if _is_concrete(task_done):
-            self._decode_cache().plan(np.asarray(task_done, bool))
+            self._decode_cache().plan(task_done)
         return self
 
 
@@ -340,7 +334,7 @@ def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
     auto.  Without ``A`` the plan is aggregation-only.
 
     ``devices`` places an MV plan's workers over those devices, ``n``
-    divided evenly among them (``repro.runtime.placement``); None keeps
+    divided evenly among them (``repro.runtime.executor``); None keeps
     them all on one device.
     """
     tr = default_tracer()
@@ -352,14 +346,9 @@ def compile_plan(A=None, *, scheme="proposed", n=None, s=None,
                           capacities=capacities)
     kind = "mm" if isinstance(sch, MMScheme) else "mv"
     if devices is not None:
-        if kind == "mm":
-            raise NotImplementedError(
-                "placing an MM plan's workers on devices is not supported; "
-                "compile it without devices=")
         if A is None:
             raise ValueError("a plan placed on devices needs its operand A")
         devices = tuple(devices)
-        workers_per_device(sch.n, len(devices))
     G = np.asarray(system_matrix(sch, seed))
     resolved = choose_backend(A, backend)
 
@@ -402,16 +391,10 @@ def _attach_operand(plan: CodedPlan, A, resolved: str) -> None:
             plan._sup_b, plan._coef_b = (
                 (None, None) if resolved == "reference"
                 else support_tables(sch.supports_B, rb))
-    if plan.devices is None:
-        plan.executor = CodedExecutor(
-            _match_dtype(coded, A), jnp.asarray(plan.G, jnp.float32), k,
-            A.shape[1], backend=resolved, cache_size=plan.cache_size)
-    else:
-        with optional_span(tr, "plan.pack", cat="plan", track="plan"):
-            plan.executor = PlacedExecutor(
-                _match_dtype(coded, A), plan.G, k, A.shape[1],
-                plan.devices, resolved, cache_size=plan.cache_size)
-        del coded       # packed a device at a time; the shards go
+    plan.executor = CodedExecutor(
+        _match_dtype(coded, A), jnp.asarray(plan.G, jnp.float32), k,
+        A.shape[1], backend=resolved, devices=plan.devices,
+        cache_size=plan.cache_size)
     plan.r = A.shape[1]
     if _is_concrete(A):
         plan._A = A

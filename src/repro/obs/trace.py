@@ -73,7 +73,7 @@ PROGRAM_SPANS = (
     "executor.worker",    # worker-kernel launch (matvec: x padded first)
     "executor.decode",    # decode-kernel launch and its operand
     "executor.output",    # reshapes and slices to the output's layout
-    # a plan placed on devices (``repro.runtime.placement``)
+    # a plan placed on devices (``repro.runtime.executor``)
     "executor.launch",    # one device: x padded there, its kernel launch
     "executor.harvest",   # last launch until k workers' results are ready
     "executor.gather",    # those results moved to the decoding device

@@ -15,10 +15,14 @@ scaling end-to-end:
   * ``executor``     -- ``CodedExecutor`` with ``reference`` / ``packed`` /
     ``pallas`` / ``pallas-interpret`` backends; every coded call site
     (``CodedOperator``, ``CodedLinear``, ``coded_matvec``/``matmat``,
-    the serving engine) routes through it.
-  * ``placement``    -- ``PlacedExecutor``: an MV plan's workers placed
-    over several devices, one launch a device, decoded from the first
-    devices to finish (``compile_plan(..., devices=)``).
+    the serving engine) routes through it.  Its kernel path runs one
+    product over device groups: an unplaced operator is one group on
+    the default device; ``devices=`` (``compile_plan(..., devices=)``)
+    places an MV operator's workers over several, one launch a device,
+    decoded from the first devices to finish.
+  * ``placement``    -- the executor's device-free placement policy:
+    workers a device, which devices to launch on, the harvest by
+    completion, tested with scripted readiness.
 
 Force a backend with the ``REPRO_CODED_BACKEND`` environment variable
 (e.g. ``REPRO_CODED_BACKEND=packed`` on CPU, ``pallas-interpret`` to
@@ -37,4 +41,3 @@ from .executor import (  # noqa: F401
     support_tables,
 )
 from .pack import PackedShards, pack_coded_blocks, unpack_coded_blocks  # noqa: F401
-from .placement import PlacedExecutor  # noqa: F401

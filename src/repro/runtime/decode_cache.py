@@ -37,15 +37,15 @@ class DecodePlan:
     key: bytes                 # canonical done-mask bytes
     rows: np.ndarray           # (k,) fastest-k task rows (host ints)
     hinv: np.ndarray           # (k, k) f32 inverse of G[rows] (host)
-    hinv_dev: jnp.ndarray      # same, device-resident for the kernels
     tables: Any = None         # the owner's per-pattern operands (the
-                               # executor's block-row table), if any
-    # hinv on each device that decodes this pattern (placed plans)
+                               # executor's block-row tables), if any
+    # hinv on each device that decodes this pattern
     copies: dict = field(default_factory=dict, compare=False, repr=False)
 
-    def hinv_on(self, device) -> jnp.ndarray:
-        """``hinv`` on ``device``: copied there on first use, kept and
-        evicted with the plan."""
+    def hinv_on(self, device=None) -> jnp.ndarray:
+        """``hinv`` on ``device`` (None: the default device, left
+        uncommitted): uploaded there on first use, kept and evicted
+        with the plan."""
         copy = self.copies.get(device)
         if copy is None:
             copy = self.copies[device] = jax.device_put(self.hinv, device)
@@ -73,8 +73,10 @@ class DecodeCache:
         # corrupt under that interleaving
         self._lock = threading.Lock()
 
-    def plan(self, done) -> DecodePlan:
-        mask = np.asarray(done, dtype=bool)
+    def plan(self, done=None) -> DecodePlan:
+        """The plan of the done mask (None: every task done)."""
+        mask = (np.ones(self._G.shape[0], bool) if done is None
+                else np.asarray(done, dtype=bool))
         if mask.ndim != 1 or mask.shape[0] != self._G.shape[0]:
             raise ValueError(
                 f"done mask shape {mask.shape} incompatible with "
@@ -93,7 +95,6 @@ class DecodeCache:
                 f"only {rows.shape[0]} tasks done, need k={self.k}")
         hinv = np.linalg.inv(self._G[rows]).astype(np.float32)
         plan = DecodePlan(key=key, rows=rows, hinv=hinv,
-                          hinv_dev=jnp.asarray(hinv),
                           tables=None if self._tables is None
                           else self._tables(rows))
         with self._lock:

@@ -1,5 +1,5 @@
 """Coded workers placed on several devices (``compile_plan(devices=)``,
-``repro.runtime.placement``).
+``repro.runtime.executor`` over the policy of ``repro.runtime.placement``).
 
 The policy (how many workers a device holds, which devices a product is
 launched on, the harvest by completion) is tested here with scripted
@@ -148,12 +148,19 @@ PROGRAM = """
            "grid_steps": [int(np.prod(bcsr_grid(
                g.packed.a_data.shape, 3 * g.packed.mb, 8)))
                for g in ex.groups],
+           # a launch over 1, 2 or 3 of a device's workers
+           "row_grid_steps": [int(np.prod(bcsr_grid(
+               ex.groups[0].packed.a_data.shape,
+               m * ex.groups[0].packed.mb, 8))) for m in (1, 2, 3)],
            "base_grid_steps": int(np.prod(bcsr_grid(
                base.executor.packed.a_data.shape,
                9 * base.executor.packed.mb, 8)))}
 
+    polls = [0]
+
     def report(name, y, done, xs=x):
         rec = ex.records[-1]
+        now = default_tracer().counters().get("executor.harvest_polls", 0)
         for g in ex.groups:             # every device idle again
             g.pending.block_until_ready()
         mask = done if done is not None else device_mask(
@@ -163,6 +170,7 @@ PROGRAM = """
         rows = np.flatnonzero(mask)[:9]
         out["calls"].append({
             "name": name, "launched": rec.launched,
+            "explicit": done is not None, "polls": now - polls[0],
             "skipped": rec.skipped_busy, "rows": rec.rows,
             "decode": rec.decode_device, "harvest_s": rec.harvest_s,
             "on": [str(d) for d in y.devices()],
@@ -171,10 +179,12 @@ PROGRAM = """
                          / np.abs(ref).max()),
             "kappa": float(np.linalg.cond(G[rows])),
             "same_as_unplaced": bool(np.array_equal(np.asarray(y), yb))})
+        polls[0] = now
 
     ex.warm(rep)
     default_tracer().clear()
     before = default_tracer().counters()
+    polls[0] = before.get("executor.harvest_polls", 0)
     report("none, replicated", plan.matvec(rep), None)
     report("none, on one device", plan.matvec(x), None)
     report("none, one row", plan.matvec(rep[0])[None], None, x[:1])
@@ -203,7 +213,8 @@ PROGRAM = """
                             A, scheme="proposed", n=8, k_A=2, k_B=2,
                             backend="pallas-interpret", devices=devs)),
                        ("uneven", lambda: compile_plan(
-                            A, devices=jax.devices()[:5], **kw))]:
+                            A, devices=jax.devices()[:5], **kw)),
+                       ("traced", lambda: jax.jit(plan.matvec)(x))]:
         try:
             call()
             out[name] = "no error"
@@ -292,11 +303,25 @@ def test_record_spans_and_counters_fill_in(placed):
     assert counters["executor.chip_launches"] == \
         sum(len(c["launched"]) for c in calls)
     assert counters["executor.chips_skipped_busy"] == 1
-    assert counters["executor.harvest_polls"] >= len(calls)
+    # a harvest polls at least once; an explicit done knows its rows
+    # before launch and polls nothing
+    assert all(c["polls"] == 0 if c["explicit"] else c["polls"] >= 1
+               for c in calls)
+    assert counters["executor.harvest_polls"] == \
+        sum(c["polls"] for c in calls)
+
+    def steps(call, c):
+        """A harvested launch reads all 3 of device c's workers; an
+        explicit done's, only its decode rows there."""
+        if not call["explicit"]:
+            return placed["grid_steps"][c]
+        return placed["row_grid_steps"][
+            sum(i // 3 == c for i in call["rows"]) - 1]
+
     # each launch adds its grid (x of 8 or 1 rows: one N tile either
     # way), and so does each call's unplaced product it is compared with
     assert counters["kernel.bcsr_grid_steps"] == sum(
-        placed["grid_steps"][c] for call in calls
+        steps(call, c) for call in calls
         for c in call["launched"]) + len(calls) * placed["base_grid_steps"]
     assert {"plan.matvec", "executor.launch", "executor.harvest",
             "executor.gather", "executor.decode",
@@ -315,6 +340,12 @@ def test_mm_and_uneven_placements_are_refused(placed):
     assert placed["mm"].startswith("NotImplementedError")
     assert placed["uneven"].startswith("ValueError")
     assert "n=12" in placed["uneven"] and "5 devices" in placed["uneven"]
+
+
+def test_traced_input_is_refused(placed):
+    # no reference fallback: the placed workers need concrete inputs
+    assert placed["traced"] == \
+        "ValueError: a placed plan needs concrete inputs"
 
 
 def test_call_record_fields():

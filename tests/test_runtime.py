@@ -121,7 +121,8 @@ class TestPacking:
         a_data = np.asarray(packed.a_data)
         for j, i in enumerate(plan.rows):
             vd, vi = (np.asarray(v) for v in packed.worker_view(int(i)))
-            for tables, at in ((plan.tables, j), (ex._worker_tables[i], 0)):
+            for tables, at in ((plan.tables[0], j),
+                               (ex.groups[0].workers[i], 0)):
                 blocks = np.asarray(tables.blocks)
                 idx = np.asarray(tables.idx)
                 lo, hi = at * packed.mb, (at + 1) * packed.mb
@@ -215,6 +216,57 @@ class TestBlockRowTables:
             before = tr.counters().get(GRID_STEPS, 0)
             call().block_until_ready()
             assert tr.counters()[GRID_STEPS] - before == steps
+
+    @pytest.mark.parametrize("stragglers", [None, [1, 4]])
+    def test_unplaced_product_is_one_launch_and_one_decode(
+            self, monkeypatch, stragglers):
+        """An unplaced MV product (rows known: one group) launches
+        ``worker_kernel`` and ``decode_kernel`` once each, polls no
+        readiness and moves nothing between devices."""
+        from collections import Counter
+
+        import repro.obs.trace as trace_mod
+        from repro.api import compile_plan
+        from repro.obs import Tracer
+        from repro.runtime import executor
+        from repro.runtime.executor import HARVEST_POLLS
+
+        tr = Tracer(capacity=4096)
+        monkeypatch.setenv("REPRO_TRACE", "1")
+        monkeypatch.setattr(trace_mod, "_GLOBAL", tr)
+        rng = np.random.default_rng(17)
+        A = jnp.asarray(rng.standard_normal((96, 64)), jnp.float32)
+        plan = compile_plan(A, scheme="proposed", n=6, s=2,
+                            backend="pallas-interpret")
+        x = jnp.asarray(rng.standard_normal((2, 96)), jnp.float32)
+        done = None
+        if stragglers is not None:
+            done = np.ones(6, bool)
+            done[stragglers] = False
+        want = np.asarray(plan.matvec(x, done))  # the pattern's plan
+        calls = Counter()
+
+        def counted(owner, name):
+            fn = getattr(owner, name)
+
+            def call(*args, **kw):
+                calls[name] += 1
+                return fn(*args, **kw)
+            monkeypatch.setattr(owner, name, call)
+
+        for owner, name in ((executor, "worker_kernel"),
+                            (executor, "decode_kernel"),
+                            (executor.CodedExecutor, "_ready"),
+                            (jax, "device_put"), (jnp, "concatenate")):
+            counted(owner, name)
+        polls = tr.counters().get(HARVEST_POLLS, 0)
+        out = plan.matvec(x, done).block_until_ready()
+        assert dict(calls) == {"worker_kernel": 1, "decode_kernel": 1}
+        assert tr.counters().get(HARVEST_POLLS, 0) == polls
+        np.testing.assert_array_equal(np.asarray(out), want)
+        rec = plan.executor.records[-1]
+        assert (rec.launched, rec.skipped_busy, rec.decode_device,
+                rec.harvest_s) == ((0,), (), 0, 0.0)
 
 
 # ---------------------------------------------------------------------------

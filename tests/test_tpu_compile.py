@@ -128,7 +128,7 @@ def test_mm_decode_compiles(spec, cfg):
 
 @pytest.mark.parametrize("chip", range(4))
 def test_mv_placed_compiles_on_each_chip(topo, chip):
-    # a plan placed over the 2x2 host (repro.runtime.placement): chip c
+    # a plan placed over the 2x2 host (repro.runtime.executor): chip c
     # launches over its own n/4 workers' tiles, and decodes the pattern
     # when it is the first to finish
     on = SingleDeviceSharding(topo.devices[chip])
